@@ -21,6 +21,7 @@ modeled-vs-measured error out.
 from __future__ import annotations
 
 from repro.autotune import calibrate, save_profile
+from repro.backend import interpret_mode
 
 
 def run(small: bool = False, profile_json: str | None = None,
@@ -46,7 +47,7 @@ def run(small: bool = False, profile_json: str | None = None,
         path = save_profile(m, meta={"err_before": res.err_before,
                                      "err_after": res.err_after,
                                      "points": len(res.points),
-                                     "interpret": True},
+                                     "interpret": interpret_mode()},
                             path=profile_json)
         rows.append(("calib/profile", 0.0, f"saved={path}"))
     return rows

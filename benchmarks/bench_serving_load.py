@@ -222,6 +222,7 @@ def run(small: bool = False, seed: int = 0,
     import jax
 
     from repro import obs
+    from repro.backend import device_label
     from repro.configs import get_smoke
     from repro.models import api
     from repro.serving.engine import Engine
@@ -290,6 +291,7 @@ def run(small: bool = False, seed: int = 0,
         "budget_fraction": 0.02,
     }
 
+    dev = device_label()
     doc = {
         "bench": "serving_load",
         "meta": {
@@ -301,7 +303,8 @@ def run(small: bool = False, seed: int = 0,
             "sparsity": 0.8,
             "head_compression_vs_dense":
                 float(sparse_head.compression_vs_dense),
-            "interpret_mode": True,
+            "interpret_mode": dev["interpret"],
+            "device": {k: dev[k] for k in ("platform", "kind", "count")},
             "platform": platform.platform(),
         },
         **results,

@@ -17,6 +17,7 @@ import numpy as np
 
 from benchmarks.suite import (cached_encode, cached_suite, model_time,
                               spmv_bytes)
+from repro.backend import device_label
 from repro.core.csr_dtans import encode_matrix
 from repro.kernels import ops
 from repro.kernels.pack import pack_matrix
@@ -125,10 +126,12 @@ def run(small: bool = False, warm: bool = True, measure: bool = True):
         us_sell = (time.time() - t0) / 3 * 1e6
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                    rtol=1e-9)
-        rows.append(("measured_cpu_interpret/dtans_spmv", us_dtans,
+        dev = device_label()
+        where = (f"{dev['platform']}_"
+                 f"{'interpret' if dev['interpret'] else 'compiled'}")
+        rows.append((f"measured_{where}/dtans_spmv", us_dtans,
                      "correctness=match"))
-        rows.append(("measured_cpu_interpret/sell_spmv", us_sell,
-                     "cpu-interpret-only"))
+        rows.append((f"measured_{where}/sell_spmv", us_sell, where))
     return rows
 
 

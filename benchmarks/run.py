@@ -70,6 +70,9 @@ def main() -> None:
                          "exhaustive oracle encodes every candidate)")
     args, _ = ap.parse_known_args()
 
+    from repro.backend import enable_compilation_cache
+    enable_compilation_cache()
+
     from benchmarks import (bench_batch_selection, bench_calibration,
                             bench_compression, bench_delta_entropy,
                             bench_format_selection, bench_kernel_tiles,

@@ -8,9 +8,10 @@ This module closes the loop three ways:
 * **Timing harness** — `spmv_runner` builds a zero-arg callable that
   runs one ``y = A x`` through the registered kernel path of any
   candidate (format, config); `time_kernel` times it with warmup,
-  ``block_until_ready`` and a median-of-k repeat. Kernels run in Pallas
-  interpret mode by default so the harness works on CPU CI hosts;
-  on-accelerator callers pass ``interpret=False`` for compiled numbers.
+  ``block_until_ready`` and a median-of-k repeat. Kernels run
+  compiled on a TPU and in Pallas interpret mode on a CPU host
+  (`repro.backend.interpret_mode`), so the harness works on CPU CI
+  hosts and times compiled kernels on the chip.
 * **Measured refinement** — `search.select(budget=k, measure=True)`
   calls `measure_candidate` on the top-k candidates so the final argmin
   ranks *measured* seconds, not modeled ones, and the measurement flows
@@ -155,7 +156,6 @@ def _default_x(a, batch: int = 1) -> np.ndarray:
 
 def spmv_runner(a, fmt: str, *, params: DtansParams = PAPER,
                 x: np.ndarray | None = None, batch: int = 1,
-                interpret: bool = True,
                 artifacts: dict | None = None, **knobs):
     """Zero-arg callable running one ``y = A x`` through the registered
     kernel path of (format, config); feed it to `time_kernel`.
@@ -197,13 +197,12 @@ def spmv_runner(a, fmt: str, *, params: DtansParams = PAPER,
     packed = spec.pack(a, params=params, artifacts=artifacts,
                        **spec.filter_knobs(knobs))
     if batch == 1:
-        return spec.runner(packed, x, interpret=interpret)
-    return spec.spmm_runner(packed, x, interpret=interpret)
+        return spec.runner(packed, x)
+    return spec.spmm_runner(packed, x)
 
 
 def measure_config(a, fmt: str, *, params: DtansParams = PAPER,
                    x: np.ndarray | None = None, batch: int = 1,
-                   interpret: bool = True,
                    warmup: int = DEFAULT_WARMUP,
                    repeats: int = DEFAULT_REPEATS,
                    artifacts: dict | None = None,
@@ -213,7 +212,7 @@ def measure_config(a, fmt: str, *, params: DtansParams = PAPER,
     in `spmv_runner`). Returns `time_kernel`'s `TimingSample` (a float
     carrying dispersion)."""
     fn = spmv_runner(a, fmt, params=params, x=x, batch=batch,
-                     interpret=interpret, artifacts=artifacts, **knobs)
+                     artifacts=artifacts, **knobs)
     return time_kernel(fn, warmup=warmup, repeats=repeats)
 
 
@@ -230,7 +229,6 @@ def parse_config_name(name: str) -> dict:
 
 def measure_named(a, config_name: str, *, params: DtansParams = PAPER,
                   x: np.ndarray | None = None, batch: int = 1,
-                  interpret: bool = True,
                   warmup: int = DEFAULT_WARMUP,
                   repeats: int = DEFAULT_REPEATS,
                   artifacts: dict | None = None) -> TimingSample:
@@ -238,21 +236,19 @@ def measure_named(a, config_name: str, *, params: DtansParams = PAPER,
     benchmarks time the exhaustive oracle's pick."""
     return measure_config(a, **parse_config_name(config_name),
                           params=params, x=x, batch=batch,
-                          interpret=interpret,
                           warmup=warmup, repeats=repeats,
                           artifacts=artifacts)
 
 
 def measure_candidate(a, cand: Candidate, *, params: DtansParams = PAPER,
                       x: np.ndarray | None = None, batch: int = 1,
-                      interpret: bool = True,
                       warmup: int = DEFAULT_WARMUP,
                       repeats: int = DEFAULT_REPEATS,
                       artifacts: dict | None = None) -> TimingSample:
     """`measure_config` keyed off a cost-model `Candidate` (the
     candidate's knobs tuple carries the full configuration)."""
     return measure_config(a, cand.fmt, params=params, x=x, batch=batch,
-                          interpret=interpret, warmup=warmup,
+                          warmup=warmup,
                           repeats=repeats, artifacts=artifacts,
                           **cand.knobs_dict())
 
@@ -374,7 +370,7 @@ def calibrate(matrices: dict | None = None, *, base: MachineModel = V5E,
               name: str | None = None, warm: bool = True,
               configs: tuple = CALIBRATION_CONFIGS,
               batches: tuple = CALIBRATION_BATCHES,
-              params: DtansParams = PAPER, interpret: bool = True,
+              params: DtansParams = PAPER,
               warmup: int = DEFAULT_WARMUP,
               repeats: int = DEFAULT_REPEATS,
               small: bool = True) -> CalibrationResult:
@@ -423,7 +419,7 @@ def calibrate(matrices: dict | None = None, *, base: MachineModel = V5E,
             for B in batches:
                 t_meas = measure_config(
                     a, spec.name, params=params, batch=B,
-                    interpret=interpret, warmup=warmup,
+                    warmup=warmup,
                     repeats=repeats, artifacts=enc, **knobs)
                 moved = spmm_bytes(nbytes, fp.cols, fp.rows,
                                    fp.value_bytes, B)

@@ -34,6 +34,7 @@ import weakref
 
 from repro import obs
 from repro.autotune.cache import DecisionCache, default_cache
+from repro.backend import interpret_mode
 from repro.autotune.cost_model import (V5E, Candidate, MachineModel,
                                        candidate_time, candidates,
                                        merge_knob_overrides,
@@ -195,7 +196,7 @@ def select(a, *, machine: MachineModel = V5E, warm: bool = True,
            batch: int = 1,
            mesh=None, n_shards: int | None = None,
            measure: bool = False, measure_warmup: int = 1,
-           measure_repeats: int = 3, interpret: bool = True,
+           measure_repeats: int = 3,
            params: DtansParams = PAPER,
            knob_overrides: dict | None = None,
            lane_widths: tuple | None = None,
@@ -237,8 +238,6 @@ def select(a, *, machine: MachineModel = V5E, warm: bool = True,
         measurement lands in ``Decision.measured_time``.
       measure_warmup / measure_repeats: timing harness knobs
         (median-of-``measure_repeats`` after ``measure_warmup`` calls).
-      interpret: run measured kernels in Pallas interpret mode (CPU CI
-        fallback); pass ``False`` on an accelerator host.
       knob_overrides: generic knob-domain overrides, ``{knob name ->
         domain tuple}`` — narrows/extends ANY format's sweep (third-
         party specs' knobs included) without a new named keyword.
@@ -292,7 +291,7 @@ def select(a, *, machine: MachineModel = V5E, warm: bool = True,
     # short-circuit on the memo.
     cfg = (machine, warm, tuple(formats), int(budget), int(batch), ks,
            ko, doms, params, cache, bool(measure), int(measure_warmup),
-           int(measure_repeats), bool(interpret))
+           int(measure_repeats), interpret_mode())
     if use_cache:
         hit = _memo.get(id(a))
         if hit is not None and hit[0]() is a and hit[1] == cfg:
@@ -317,7 +316,7 @@ def select(a, *, machine: MachineModel = V5E, warm: bool = True,
         # harness knobs): the currencies must never be mixed by a
         # cache hit.
         key_parts.append(f"meas:w{int(measure_warmup)}"
-                         f"r{int(measure_repeats)}i{int(interpret)}")
+                         f"r{int(measure_repeats)}i{int(interpret_mode())}")
     key = "|".join(key_parts)
     if use_cache:
         raw = cache.get(key)
@@ -358,7 +357,7 @@ def select(a, *, machine: MachineModel = V5E, warm: bool = True,
             from repro.autotune.measure import measure_candidate
             head = [dataclasses.replace(
                         h, measured_time=measure_candidate(
-                            a, h, params=params, interpret=interpret,
+                            a, h, params=params,
                             warmup=measure_warmup, batch=batch,
                             repeats=measure_repeats, artifacts=arts))
                     for h in head]
@@ -401,7 +400,7 @@ def choose_dtans_config(a, *, machine: MachineModel = V5E,
                         warm: bool = True, budget: int = 0,
                         batch: int = 1,
                         mesh=None, n_shards: int | None = None,
-                        measure: bool = False, interpret: bool = True,
+                        measure: bool = False,
                         params: DtansParams = PAPER,
                         cache: DecisionCache | None = None,
                         use_cache: bool = True,
@@ -421,5 +420,5 @@ def choose_dtans_config(a, *, machine: MachineModel = V5E,
                   formats=format_names(selectable=True, decodes=True),
                   budget=budget, batch=batch, mesh=mesh,
                   n_shards=n_shards, measure=measure,
-                  interpret=interpret, params=params, cache=cache,
+                  params=params, cache=cache,
                   use_cache=use_cache, artifacts=artifacts)

@@ -1,19 +1,17 @@
-"""BCSR SpMVM Pallas kernel (interpret-mode first, like sell_spmv).
+"""BCSR SpMM Pallas kernel.
 
-One program per block row. The block row's blocks live in VMEM as a
-(W, r, c) value tile plus a (W,) block-column vector (W = matrix-wide
-max blocks per block row — address padding only, like `pack.py`'s
-stream padding; padded slots carry block column -1 and zero values).
-The kernel expands each block column into its c absolute columns,
-gathers x once per block, and contracts the dense r x c tiles — no
-per-element index arithmetic, which is the format's whole bargain: the
-cost model charges BCSR plain lock-step work over the *filled* cells
+The pack stores the blocks rows-on-lanes: for each of the ``W`` block
+slots of a block row (``W`` = matrix-wide max blocks per block row —
+address padding only, like `pack.py`'s stream padding; padded slots carry
+block column -1 and zero values), every row of the block row holds the
+block's column and its ``c`` values — ``(W, Mp)`` block columns and
+``(W, c, Mp)`` values.  One program per 128 rows walks the block slots,
+expands each block column into its ``c`` absolute columns, gathers ``x``
+(one-hot on the MXU) and contracts the dense r x c tiles — no per-element
+index storage, which is the format's whole bargain: the cost model
+charges BCSR plain lock-step work over the *filled* cells
 (`Fingerprint.block_fill_elems`), with no row-sequential penalty and no
 decode term.
-
-Structure mirrors `sell_spmv.py` / `rgcsr_spmv.py`: a dataclass pack
-product, a Pallas kernel over a 1-D block-row grid, and a pure-jnp
-oracle for tests.
 """
 
 from __future__ import annotations
@@ -26,14 +24,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import LANES, fori, gather_mul
+from repro.kernels.sell_spmv import padded_rows
 from repro.kernels.tiling import blocked_spmm
 from repro.sparse.bcsr import BCSR
 
 
 @dataclasses.dataclass
 class PackedBCSR:
-    block_cols: np.ndarray  # (S, W) int32 block-column ids, -1 = padding
-    values: np.ndarray      # (S, W, r, c)
+    block_cols: np.ndarray  # (W, Mp) int32 block-column per row, -1 = pad
+    values: np.ndarray      # (W, c, Mp)
     shape: tuple
     block_shape: tuple
 
@@ -43,96 +43,68 @@ def pack_bcsr(b: BCSR) -> PackedBCSR:
     S = b.n_block_rows
     per_row = np.diff(b.block_ptr)
     W = max(int(per_row.max()) if S else 0, 1)
-    cols = np.full((S, W), -1, dtype=np.int32)
-    vals = np.zeros((S, W, r, c), dtype=b.values.dtype)
+    Mp = padded_rows(S * r)
+    cols = np.full((W, Mp), -1, dtype=np.int32)
+    vals = np.zeros((W, c, Mp), dtype=b.values.dtype)
     if b.n_blocks:
-        # Vectorized scatter: each block lands at (its block row, its
-        # position within that row).
+        # Each block lands at (its slot in its block row, its r rows).
         brow = np.repeat(np.arange(S, dtype=np.int64), per_row)
         pos = np.arange(b.n_blocks, dtype=np.int64) - b.block_ptr[brow]
-        cols[brow, pos] = b.block_cols
-        vals[brow, pos] = b.values
+        rows = brow[:, None] * r + np.arange(r)            # (nb, r)
+        cols[pos[:, None], rows] = np.asarray(b.block_cols)[:, None]
+        vals[pos[:, None, None], np.arange(c)[None, :, None],
+             rows[:, None, :]] = np.asarray(b.values).transpose(0, 2, 1)
     return PackedBCSR(block_cols=cols, values=vals, shape=b.shape,
                       block_shape=b.block_shape)
 
 
 def _bcsr_kernel(col_ref, val_ref, x_ref, y_ref):
-    cols = col_ref[0]         # (W,)
-    vals = val_ref[0]         # (W, r, c)
-    x = x_ref[...]
-    W, r, c = vals.shape
-    n = x.shape[0]
-    mask = cols >= 0
-    # absolute columns per block: (W, c), clipped into x (padded slots
-    # and out-of-bounds edge-block cells hold zero values, so the
-    # clipped gather contributes nothing)
-    colidx = jnp.maximum(cols, 0)[:, None] * c + \
-        jax.lax.broadcasted_iota(jnp.int32, (W, c), 1)
-    xg = jnp.take(x, jnp.clip(colidx, 0, n - 1), axis=0)   # (W, c)
-    contrib = jnp.where(mask[:, None, None], vals * xg[:, None, :], 0)
-    y_ref[0, :] = jnp.sum(contrib, axis=(0, 2))            # (r,)
+    xt = x_ref[...]                                   # (Bt, n)
+    c = val_ref.shape[1]
 
+    def body(w, acc):
+        bc = col_ref[pl.ds(w, 1), :]                  # (1, 128)
+        vt = val_ref[w]                               # (c, 128)
+        s = None
+        for cc in range(c):
+            t = gather_mul(xt, bc * c + cc, vt[cc:cc + 1])  # bc == -1: pad
+            s = t if s is None else s + t
+        return acc + s
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def bcsr_spmv_pallas(block_cols, val, x, interpret=True):
-    S, W, r, c = val.shape
-    n = x.shape[0]
-    return pl.pallas_call(
-        _bcsr_kernel,
-        grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, W), lambda s: (s, 0)),
-            pl.BlockSpec((1, W, r, c), lambda s: (s, 0, 0, 0)),
-            pl.BlockSpec((n,), lambda s: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, r), lambda s: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, r), val.dtype),
-        interpret=interpret,
-    )(block_cols, val, x)
-
-
-def _bcsr_spmm_kernel(col_ref, val_ref, x_ref, y_ref):
-    cols = col_ref[0]         # (W,)
-    vals = val_ref[0]         # (W, r, c)
-    x = x_ref[...]            # (n, B)
-    W, r, c = vals.shape
-    n = x.shape[0]
-    mask = cols >= 0
-    colidx = jnp.maximum(cols, 0)[:, None] * c + \
-        jax.lax.broadcasted_iota(jnp.int32, (W, c), 1)
-    xg = jnp.take(x, jnp.clip(colidx, 0, n - 1), axis=0)   # (W, c, B)
-    contrib = jnp.where(mask[:, None, None, None],
-                        vals[..., None] * xg[:, None, :, :], 0)
-    y_ref[0, :, :] = jnp.sum(contrib, axis=(0, 2))         # (r, B)
+    y_ref[...] = fori(col_ref.shape[0], body,
+                      jnp.zeros(y_ref.shape, y_ref.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn",
                                              "tile_mode"))
-def bcsr_spmm_pallas(block_cols, val, x, interpret=True, bn=None,
+def bcsr_spmm_pallas(block_cols, val, x, interpret=None, bn=None,
                      tile_mode="auto"):
-    """Multi-RHS BCSR kernel: x is (n, B); returns (S, r, B) — each
-    dense tile is gathered once and contracted against all B columns.
-    ``bn`` column-tiles the B axis (`repro.kernels.tiling`); blocked
-    output is bitwise equal to the untiled kernel."""
-    S, W, r, c = val.shape
-    mat_specs = [
-        ((1, W), lambda s: (s, 0)),
-        ((1, W, r, c), lambda s: (s, 0, 0, 0)),
-    ]
-    return blocked_spmm(_bcsr_spmm_kernel, (block_cols, val), mat_specs,
-                        x, rows=r, out_dtype=val.dtype, grid_s=S, bn=bn,
-                        tile_mode=tile_mode, interpret=interpret)
+    """Multi-RHS BCSR kernel: x is (n, B); returns (Mp, B) rows — each
+    block's x columns are gathered once and contracted against all B
+    right-hand sides.  ``bn`` column-tiles the B axis
+    (`repro.kernels.tiling`); blocked output is bitwise equal to the
+    untiled kernel."""
+    W, c, Mp = val.shape
+    return blocked_spmm(_bcsr_kernel, (block_cols, val),
+                        [((W, LANES), lambda g: (0, g)),
+                         ((W, c, LANES), lambda g: (0, 0, g))],
+                        x.astype(val.dtype), lanes=LANES,
+                        grid_s=Mp // LANES, bn=bn, tile_mode=tile_mode,
+                        interpret=interpret)
+
+
+def bcsr_spmv_pallas(block_cols, val, x, interpret=None):
+    return bcsr_spmm_pallas(block_cols, val, x[:, None],
+                            interpret=interpret)[:, 0]
 
 
 def bcsr_spmv_ref(block_cols: np.ndarray, val: np.ndarray, x: np.ndarray):
-    """Pure-jnp oracle for the BCSR kernel ((S, r) output)."""
+    """Pure-jnp oracle for the BCSR kernel ((Mp,) rows)."""
     x = jnp.asarray(x)
-    S, W, r, c = val.shape
+    W, c, Mp = val.shape
     n = x.shape[0]
-    mask = block_cols >= 0
-    colidx = jnp.maximum(block_cols, 0)[..., None] * c + \
-        jax.lax.broadcasted_iota(jnp.int32, (S, W, c), 2)
-    xg = jnp.take(x, jnp.clip(colidx, 0, n - 1), axis=0)   # (S, W, c)
-    contrib = jnp.where(mask[..., None, None],
-                        val * xg[:, :, None, :], 0)
-    return jnp.sum(contrib, axis=(1, 3))                   # (S, r)
+    colidx = jnp.maximum(block_cols, 0)[:, None, :] * c + \
+        jax.lax.broadcasted_iota(jnp.int32, (W, c, Mp), 1)
+    xg = jnp.take(x, jnp.clip(colidx, 0, n - 1), axis=0)   # (W, c, Mp)
+    contrib = jnp.where((block_cols >= 0)[:, None, :], val * xg, 0)
+    return jnp.sum(contrib, axis=(0, 1))

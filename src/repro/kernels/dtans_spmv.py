@@ -1,51 +1,39 @@
-"""Fused dtANS-decode + SpMVM Pallas TPU kernel (the paper's Fig. 1 right).
+"""Fused dtANS-decode + SpMM Pallas TPU kernel (the paper's Fig. 1 right).
 
-Grid: one program per slice of ``lane_width`` rows (the TPU translation of
-one GPU warp per 32-row slice). Per program, the kernel holds in VMEM:
+Grid: one program per group of ``P`` slices of ``lane_width`` rows — 128
+lanes in all when the lane width divides 128 (`repro.kernels.pack`).  Per
+program, the kernel holds in VMEM:
 
-  stream block   (1, Wmax)  x 8 B   — this slice's interleaved word stream
-  escape block   (T, 1, Emax) x 8 B — this slice's escape streams
-  coding tables  (T, K) x 20 B      — shared by every program (K = 4096
-                                      -> 80 KB/table; fits v5e VMEM easily)
-  x              (n,) x itemsize    — the dense input vector
-  y block        (1, L) x itemsize  — output rows for this slice
+  stream block   (Rw, 128) x 4 B    — the group's word streams
+  escape block   (T, Re, 128)       — the group's escape streams
+  lane vectors   (1, N) x 4 B each  — slice offsets, segment / nnz counts
+  coding tables  (T, Rk, 128) x 8 B — symbol and digit/base/escape words,
+                                      shared by every program
+  x^T tile       (Bt, n)            — the dense right-hand sides
+  y^T block      (Bt, N)            — output rows for this group
 
 The decode loop is `lax.fori_loop` over the matrix-wide max segment count;
 lanes past their row's end are masked (same lock-step schedule as
-`repro.core.dtans_vec.decode_lanes`). All gathers (stream claims, table
-lookups, x[col]) are `jnp.take` over VMEM-resident blocks — the TPU
-equivalent of the paper's shared-memory lookups + coalesced loads
-(DESIGN.md §2 spells out the mapping and its costs).
+`repro.core.dtans_vec.decode_lanes`).  Stream claims and table lookups are
+in-tile gathers (`repro.kernels.common.lookup`); each ``value * x[col]``
+is a value-carrying one-hot product on the MXU
+(`repro.kernels.common.gather_mul`), and the products are summed in the
+same order on every path, so all schedules below are bitwise identical to
+the plain kernel.
 
-Three static knobs grow the PR-5 kernels into the blocked/fused/
-pipelined execution layer (docs/kernels.md has the full contract):
+Three static knobs (docs/kernels.md has the full contract):
 
 * ``shared_cols`` — the fused BCSR-dtANS contraction.  A block-filled
-  encode (BCSR-dtANS at lane_width == r) gives every in-bounds lane of
-  a slice the SAME column sequence, so the kernel gathers x once per
-  decoded cell from lane 0's columns (``cols[:, 0]``) and broadcasts
-  the ``(h, B)`` tile across the r lanes — an r x cut in gather traffic
-  versus the generic ``(h, L, B)`` gather.  The contraction stays in
-  multiply-where-sum form (NOT `lax.dot_general`, whose reduction tree
-  differs in the last ulp), so fused output is bitwise identical to the
-  generic path.
-* ``pipeline`` — decode/contract overlap (the SMASH co-design point):
-  the loop body decodes segment ``j+1`` BEFORE contracting segment
-  ``j``, so the next segment's stream claims and table gathers have no
-  data dependence on the in-flight contraction and can overlap it
-  (software pipelining; Mosaic/the VLIW scheduler interleaves the two
-  issue streams).  The contraction order per column is unchanged —
-  bit-identical to the serial loop.  The prologue decodes segment 0;
-  the final body iteration decodes one segment past the end, which is
-  masked to a no-op (``segment_step`` is inactive-safe).
-* ``bn`` — column tiling of the SpMM wrapper via
-  `repro.kernels.tiling.blocked_spmm` (2-D ``(s, j)`` grid compiled,
-  `lax.map` column loop in interpret mode), so x/y need never be VMEM-
-  resident whole.
-
-Validated with ``interpret=True`` (this container is CPU-only); the target
-is TPU v5e. 64-bit lane arithmetic lowers to 32-bit pairs on TPU — the
-native-width variant is a recorded perf iteration, not a correctness issue.
+  encode (BCSR-dtANS at lane_width == r) gives every in-bounds lane of a
+  slice the SAME column sequence, so every lane contracts against its
+  slice's first lane's columns.
+* ``pipeline`` — decode/contract overlap: the loop body decodes segment
+  ``j+1`` BEFORE contracting segment ``j``, so the next segment's claims
+  and lookups have no data dependence on the in-flight contraction.  The
+  prologue decodes segment 0; the final body iteration decodes one
+  segment past the end, which is masked to a no-op.
+* ``bn`` — column tiling of the right-hand sides via
+  `repro.kernels.tiling.blocked_spmm`.
 """
 
 from __future__ import annotations
@@ -54,12 +42,23 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.params import DtansParams
-from repro.kernels.common import (DecodeArrays, bits_to_value, init_state,
+from repro.kernels.common import (DecodeArrays, bits_to_value, fori,
+                                  gather_mul, init_state, lane_matrices,
                                   segment_step)
 from repro.kernels.tiling import blocked_spmm
+
+
+def load_arrays(stream_ref, sbase_ref, esc_ref, ebase_ref, nsegs_ref,
+                nnz_ref, sym_ref, meta_ref, *, lane_width: int):
+    """One program's `DecodeArrays` from its (squeezed) blocks."""
+    nsegs = nsegs_ref[...]
+    tri, grp = lane_matrices(nsegs.shape[1], lane_width)
+    return DecodeArrays(stream=stream_ref[...], stream_base=sbase_ref[...],
+                        esc=esc_ref[...], esc_base=ebase_ref[...],
+                        tab_symbol=sym_ref[...], tab_meta=meta_ref[...],
+                        nsegs=nsegs, nnz=nnz_ref[...], tri=tri, grp=grp)
 
 
 def _decode_contract(arr, params, pattern, max_nseg, acc0, contract,
@@ -76,164 +75,120 @@ def _decode_contract(arr, params, pattern, max_nseg, acc0, contract,
                                                      params, pattern)
             return state, contract(cols, vbits, valid, acc)
 
-        _, acc = jax.lax.fori_loop(0, max_nseg, body, (state, acc0))
+        _, acc = fori(max_nseg, body, (state, acc0))
         return acc
 
-    state, cols, vbits, valid = segment_step(0, state, arr, params,
-                                             pattern)
+    def step(j, state):
+        state, cols, vbits, valid = segment_step(j, state, arr, params,
+                                                 pattern)
+        # Masks cross the loop boundary as int32 (no i1 loop carries).
+        return state, (cols, vbits, [v.astype(jnp.int32) for v in valid])
 
     def body(j, carry):
         state, seg, acc = carry
-        nstate, ncols, nvbits, nvalid = segment_step(j + 1, state, arr,
-                                                     params, pattern)
-        acc = contract(*seg, acc)
-        return nstate, (ncols, nvbits, nvalid), acc
+        nstate, nseg = step(j + 1, state)
+        cols, vbits, valid = seg
+        acc = contract(cols, vbits, [v > 0 for v in valid], acc)
+        return nstate, nseg, acc
 
-    _, _, acc = jax.lax.fori_loop(0, max_nseg, body,
-                                  (state, (cols, vbits, valid), acc0))
+    state, seg = step(0, state)
+    _, _, acc = fori(max_nseg, body, (state, seg, acc0))
     return acc
 
 
-def _spmv_kernel(stream_ref, esc_ref, ns_ref, nnz_ref, sym_ref, dig_ref,
-                 base_ref, isesc_ref, x_ref, y_ref, *, params: DtansParams,
-                 pattern: tuple, max_nseg: int, out_dtype,
-                 pipeline: bool = False, shared_cols: bool = False):
-    arr = DecodeArrays(
-        stream=stream_ref[0, :],
-        esc=esc_ref[:, 0, :],
-        tab_symbol=sym_ref[...],
-        tab_digit=dig_ref[...],
-        tab_base=base_ref[...],
-        tab_is_esc=isesc_ref[...],
-        ns=ns_ref[0, :],
-        nnz=nnz_ref[0, :],
-    )
-    x = x_ref[...]
-    n = x.shape[0]
-    acc0 = jnp.zeros((arr.ns.shape[0],), dtype=out_dtype)
-
-    def contract(cols, vbits, valid, acc):
-        vals = bits_to_value(vbits, out_dtype)
-        if shared_cols:
-            # Block-filled encode: all in-bounds lanes share lane 0's
-            # columns — gather once, broadcast across the r lanes.
-            xg = jnp.take(x, jnp.clip(cols[:, 0], 0, n - 1), axis=0)
-            contrib = jnp.where(valid, vals * xg[:, None], 0)
-        else:
-            xg = jnp.take(x, jnp.clip(cols, 0, n - 1), axis=0)
-            contrib = jnp.where(valid, vals * xg, 0)
-        return acc + jnp.sum(contrib, axis=0)
-
-    y_ref[0, :] = _decode_contract(arr, params, pattern, max_nseg, acc0,
-                                   contract, pipeline)
+def _first_lane_matrix(n_lanes: int, lane_width: int):
+    """``F[i, j] = 1`` where lane ``i`` is the first lane of lane ``j``'s
+    slice: ``cols @ F`` hands every lane its slice's first column."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (n_lanes, n_lanes), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (n_lanes, n_lanes), 1)
+    if n_lanes > lane_width:   # several slices: lane_width is a power of 2
+        sh = int(lane_width).bit_length() - 1
+        first = (j >> sh) << sh
+    else:
+        first = 0
+    return (i == first).astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "params", "pattern", "max_nseg", "lane_width", "out_dtype",
-    "interpret", "pipeline", "shared_cols"))
-def dtans_spmv_pallas(stream, esc, ns, nnz, tabs, x, *, params, pattern,
-                      max_nseg, lane_width, out_dtype, interpret=True,
-                      pipeline=False, shared_cols=False):
-    """pallas_call wrapper: returns per-slice row results (S, L)."""
-    S, Wmax = stream.shape
-    T, _, Emax = esc.shape
-    K = params.K
-    n = x.shape[0]
-    kernel = functools.partial(_spmv_kernel, params=params, pattern=pattern,
-                               max_nseg=max_nseg, out_dtype=out_dtype,
-                               pipeline=pipeline, shared_cols=shared_cols)
-    return pl.pallas_call(
-        kernel,
-        grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, Wmax), lambda s: (s, 0)),      # stream slice
-            pl.BlockSpec((T, 1, Emax), lambda s: (0, s, 0)),  # escapes
-            pl.BlockSpec((1, lane_width), lambda s: (s, 0)),  # ns
-            pl.BlockSpec((1, lane_width), lambda s: (s, 0)),  # nnz
-            pl.BlockSpec((T, K), lambda s: (0, 0)),          # tab symbol
-            pl.BlockSpec((T, K), lambda s: (0, 0)),          # tab digit
-            pl.BlockSpec((T, K), lambda s: (0, 0)),          # tab base
-            pl.BlockSpec((T, K), lambda s: (0, 0)),          # tab is_esc
-            pl.BlockSpec((n,), lambda s: (0,)),              # x (whole)
-        ],
-        out_specs=pl.BlockSpec((1, lane_width), lambda s: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, lane_width), out_dtype),
-        interpret=interpret,
-    )(stream, esc, ns, nnz, *tabs, x)
-
-
-def _spmm_kernel(stream_ref, esc_ref, ns_ref, nnz_ref, sym_ref, dig_ref,
-                 base_ref, isesc_ref, x_ref, y_ref, *, params: DtansParams,
-                 pattern: tuple, max_nseg: int, out_dtype,
-                 pipeline: bool = False, shared_cols: bool = False):
+def _spmm_kernel(stream_ref, sbase_ref, esc_ref, ebase_ref, nsegs_ref,
+                 nnz_ref, sym_ref, meta_ref, x_ref, y_ref, *,
+                 params: DtansParams, pattern: tuple, max_nseg: int,
+                 lane_width: int, pipeline: bool, shared_cols: bool):
     """Fused decode + multi-RHS contraction: decode each segment ONCE,
     contract it against all B columns of x before the next segment —
     the amortization the batched cost model prices (decode work is per
     matrix, contraction work per right-hand side)."""
-    arr = DecodeArrays(
-        stream=stream_ref[0, :],
-        esc=esc_ref[:, 0, :],
-        tab_symbol=sym_ref[...],
-        tab_digit=dig_ref[...],
-        tab_base=base_ref[...],
-        tab_is_esc=isesc_ref[...],
-        ns=ns_ref[0, :],
-        nnz=nnz_ref[0, :],
-    )
-    x = x_ref[...]                               # (n, B)
-    n = x.shape[0]
-    acc0 = jnp.zeros((arr.ns.shape[0], x.shape[1]), dtype=out_dtype)
+    arr = load_arrays(stream_ref, sbase_ref, esc_ref, ebase_ref, nsegs_ref,
+                      nnz_ref, sym_ref, meta_ref, lane_width=lane_width)
+    xt = x_ref[...]                                    # (Bt, n)
+    acc0 = jnp.zeros((xt.shape[0], arr.nsegs.shape[1]), xt.dtype)
+    first = (_first_lane_matrix(arr.nsegs.shape[1], lane_width)
+             if shared_cols else None)
 
     def contract(cols, vbits, valid, acc):
-        vals = bits_to_value(vbits, out_dtype)               # (h, L)
-        if shared_cols:
-            # Fused BCSR-dtANS: one (h, B) gather from lane 0's columns
-            # feeds all r lanes of the block row (r x fewer gathers).
-            xg = jnp.take(x, jnp.clip(cols[:, 0], 0, n - 1),
-                          axis=0)                            # (h, B)
-            contrib = jnp.where(valid[..., None],
-                                vals[..., None] * xg[:, None, :], 0)
-        else:
-            xg = jnp.take(x, jnp.clip(cols, 0, n - 1),
-                          axis=0)                            # (h, L, B)
-            contrib = jnp.where(valid[..., None],
-                                vals[..., None] * xg, 0)
-        return acc + jnp.sum(contrib, axis=0)
+        s = None
+        for c, vb, ok in zip(cols, vbits, valid):
+            if shared_cols:
+                c = jnp.dot(c.astype(jnp.float32), first,
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32
+                            ).astype(jnp.int32)
+            v = jnp.where(ok, bits_to_value(vb, xt.dtype), 0)
+            contrib = gather_mul(xt, c, v)             # (Bt, N)
+            s = contrib if s is None else s + contrib
+        return acc + s
 
-    y_ref[0, :, :] = _decode_contract(arr, params, pattern, max_nseg,
-                                      acc0, contract, pipeline)
+    y_ref[...] = _decode_contract(arr, params, pattern, max_nseg, acc0,
+                                  contract, pipeline)
+
+
+def mat_specs(mats):
+    """``(block_shape, index_map)`` per packed operand (`PackedMatrix`
+    field order): squeezed group dimension, full trailing dimensions."""
+    stream, sbase, esc, ebase, nsegs, nnz, sym, meta = mats
+    G, Rw, W = stream.shape
+    T, _, Re, _ = esc.shape
+    N = nsegs.shape[2]
+    lanes = (None, 1, N)
+    return [
+        ((None, Rw, W), lambda g: (g, 0, 0)),          # stream rows
+        (lanes, lambda g: (g, 0, 0)),                   # stream_base
+        ((T, None, Re, W), lambda g: (0, g, 0, 0)),     # escape rows
+        ((T, None, 1, N), lambda g: (0, g, 0, 0)),      # esc_base
+        (lanes, lambda g: (g, 0, 0)),                   # nsegs
+        (lanes, lambda g: (g, 0, 0)),                   # nnz
+        (sym.shape, lambda g: (0, 0, 0)),               # tab symbol
+        (meta.shape, lambda g: (0, 0, 0)),              # tab meta
+    ]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "params", "pattern", "max_nseg", "lane_width", "out_dtype",
-    "interpret", "bn", "tile_mode", "pipeline", "shared_cols"))
-def dtans_spmm_pallas(stream, esc, ns, nnz, tabs, x, *, params, pattern,
-                      max_nseg, lane_width, out_dtype, interpret=True,
-                      bn=None, tile_mode="auto", pipeline=False,
-                      shared_cols=False):
-    """Multi-RHS pallas_call wrapper: x is (n, B); returns (S, L, B).
+    "params", "pattern", "max_nseg", "lane_width", "interpret", "bn",
+    "tile_mode", "pipeline", "shared_cols"))
+def dtans_spmm_pallas(mats, x, *, params, pattern, max_nseg, lane_width,
+                      interpret=None, bn=None, tile_mode="auto",
+                      pipeline=False, shared_cols=False):
+    """Multi-RHS pallas_call wrapper.  ``mats`` holds the `PackedMatrix`
+    arrays in field order (stream .. tab_meta); x is (n, B) in the
+    output dtype; returns (G * N, B) rows.
 
-    ``bn`` tiles the B axis into column blocks (None = untiled single
-    tile, the PR-5 call); ``pipeline`` overlaps decode with
-    contraction; ``shared_cols`` runs the fused block-decode
-    contraction.  All three are bit-identity-preserving."""
-    S, Wmax = stream.shape
-    T, _, Emax = esc.shape
-    K = params.K
+    ``bn`` tiles the B axis into column blocks (None = one tile);
+    ``pipeline`` overlaps decode with contraction; ``shared_cols`` runs
+    the fused block-decode contraction.  All three are
+    bit-identity-preserving.  ``interpret`` defaults to the backend's
+    (`repro.backend.interpret_mode`)."""
     kernel = functools.partial(_spmm_kernel, params=params, pattern=pattern,
-                               max_nseg=max_nseg, out_dtype=out_dtype,
+                               max_nseg=max_nseg, lane_width=lane_width,
                                pipeline=pipeline, shared_cols=shared_cols)
-    mat_specs = [
-        ((1, Wmax), lambda s: (s, 0)),           # stream slice
-        ((T, 1, Emax), lambda s: (0, s, 0)),     # escapes
-        ((1, lane_width), lambda s: (s, 0)),     # ns
-        ((1, lane_width), lambda s: (s, 0)),     # nnz
-        ((T, K), lambda s: (0, 0)),              # tab symbol
-        ((T, K), lambda s: (0, 0)),              # tab digit
-        ((T, K), lambda s: (0, 0)),              # tab base
-        ((T, K), lambda s: (0, 0)),              # tab is_esc
-    ]
-    return blocked_spmm(kernel, (stream, esc, ns, nnz, *tabs), mat_specs,
-                        x, rows=lane_width, out_dtype=out_dtype,
-                        grid_s=S, bn=bn, tile_mode=tile_mode,
-                        interpret=interpret)
+    return blocked_spmm(kernel, mats, mat_specs(mats), x,
+                        lanes=mats[4].shape[2], grid_s=mats[0].shape[0],
+                        bn=bn, tile_mode=tile_mode, interpret=interpret)
+
+
+def dtans_spmv_pallas(mats, x, *, params, pattern, max_nseg, lane_width,
+                      interpret=None, pipeline=False, shared_cols=False):
+    """Single-vector form of `dtans_spmm_pallas` (x is (n,)); returns
+    (G * N,) rows — the same kernel at B = 1."""
+    return dtans_spmm_pallas(
+        mats, x[:, None], params=params, pattern=pattern,
+        max_nseg=max_nseg, lane_width=lane_width, interpret=interpret,
+        pipeline=pipeline, shared_cols=shared_cols)[:, 0]

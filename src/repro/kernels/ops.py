@@ -2,15 +2,19 @@
 
 `spmv` is the user-facing  y = A x + y  on a CSR-dtANS matrix: it packs the
 format once (cached on the object), moves tensors to device, and dispatches
-to the fused Pallas kernel (interpret=True on CPU hosts, compiled on TPU).
+to the fused Pallas kernel — interpreted on a CPU host, compiled on a TPU
+(`repro.backend.interpret_mode`; any other backend raises).  What the
+compiled kernels cannot serve raises on TPU instead of falling back:
+float64 matrices, lane widths that do not divide 128, and ``x`` longer
+than `repro.kernels.common.MAX_X_ROWS`.
 
 Every single-vector entry point has a multi-RHS sibling (`spmm`,
 `sell_spmm`, `rgcsr_spmm`, `bcsr_spmm`): ``x`` is (n, B), the result
 (m, B), and the matrix (for the dtANS family: the *decode*) is paid once
 for all B columns — the batched serving path `SparseLinear.apply`
-routes through. All eight share the ``(mat, x, y=None, *, interpret=)``
-signature; B == 1 delegates to the single-vector kernel, so spmm results
-at B=1 are bit-identical to spmv.
+routes through. All eight share the ``(mat, x, y=None)`` signature;
+B == 1 delegates to the single-vector kernel, so spmm results at B=1
+are bit-identical to spmv.
 
 `spmv` / `spmm` additionally take ``mesh=`` / ``n_shards=``: with more
 than one shard the matrix is row-partitioned along decode-slice
@@ -29,17 +33,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.backend import interpret_mode
 from repro.core.csr_dtans import CSRdtANS
 from repro.kernels import tiling
-from repro.kernels.bcsr_spmv import (PackedBCSR, bcsr_spmm_pallas,
-                                     bcsr_spmv_pallas)
+from repro.kernels.bcsr_spmv import PackedBCSR, bcsr_spmm_pallas
+from repro.kernels.common import LANES, MAX_X_ROWS
 from repro.kernels.dtans_decode import dtans_decode_pallas
 from repro.kernels.dtans_spmv import dtans_spmm_pallas, dtans_spmv_pallas
-from repro.kernels.pack import PackedMatrix, pack_matrix
-from repro.kernels.rgcsr_spmv import (PackedRGCSR, rgcsr_spmm_pallas,
-                                      rgcsr_spmv_pallas)
-from repro.kernels.sell_spmv import (PackedSELL, sell_spmm_pallas,
-                                     sell_spmv_pallas)
+from repro.kernels.pack import PackedMatrix, pack_matrix, slice_major
+from repro.kernels.rgcsr_spmv import PackedRGCSR, rgcsr_spmm_pallas
+from repro.kernels.sell_spmv import PackedSELL, sell_spmm_pallas
 
 _PACK_CACHE_FIELD = "_packed_cache"
 _OBS_NBYTES_FIELD = "_obs_nbytes"
@@ -110,7 +113,21 @@ def out_dtype(pm: PackedMatrix):
     return jnp.float64 if pm.dtype == np.float64 else jnp.float32
 
 
-_out_dtype = out_dtype   # backwards-compatible alias
+def check_compiled(kind: str, dtype, n: int, lanes: int = LANES) -> None:
+    """Raise for what the compiled (TPU) kernels cannot serve; nothing
+    to check in interpret mode."""
+    if interpret_mode():
+        return
+    if np.dtype(dtype) == np.float64:
+        raise TypeError(f"{kind}: float64 matrices have no TPU kernel "
+                        "(the chip has no 64-bit floats); use float32")
+    if lanes != LANES:
+        raise ValueError(f"{kind}: a kernel program spans {lanes} lanes; "
+                         f"the TPU kernel needs {LANES} (a lane width "
+                         f"that divides {LANES})")
+    if n > MAX_X_ROWS:
+        raise ValueError(f"{kind}: x has {n} rows; the compiled one-hot x "
+                         f"gather serves at most {MAX_X_ROWS}")
 
 
 def get_packed(mat: CSRdtANS) -> PackedMatrix:
@@ -121,9 +138,16 @@ def get_packed(mat: CSRdtANS) -> PackedMatrix:
     return pm
 
 
-def _tabs(pm: PackedMatrix):
-    return (jnp.asarray(pm.tab_symbol), jnp.asarray(pm.tab_digit),
-            jnp.asarray(pm.tab_base), jnp.asarray(pm.tab_is_esc))
+def packed_arrays(pm: PackedMatrix):
+    """The kernel operands of a pack, in `PackedMatrix` field order."""
+    return tuple(jnp.asarray(a) for a in (
+        pm.stream, pm.stream_base, pm.esc, pm.esc_base, pm.nsegs, pm.nnz,
+        pm.tab_symbol, pm.tab_meta))
+
+
+def _statics(pm: PackedMatrix) -> dict:
+    return dict(params=pm.params, pattern=pm.pattern, max_nseg=pm.max_nseg,
+                lane_width=pm.lane_width)
 
 
 def _resolve_shards(mesh, n_shards) -> int:
@@ -161,8 +185,8 @@ def get_shard_plan(mat: CSRdtANS, n_shards: int):
     return plan
 
 
-def _sharded_dtans(mat, x, y, *, mesh, k, interpret, spmm: bool,
-                   bn=None, pipeline: bool = False):
+def _sharded_dtans(mat, x, y, *, mesh, k, spmm: bool, bn=None,
+                   pipeline: bool = False):
     from repro.kernels import shard_ops
     if not isinstance(mat, CSRdtANS):
         raise TypeError(
@@ -171,11 +195,9 @@ def _sharded_dtans(mat, x, y, *, mesh, k, interpret, spmm: bool,
             "matrix object or shards=1")
     plan = get_shard_plan(mat, k)
     if spmm:
-        return shard_ops.shard_spmm(plan, x, y=y, mesh=mesh,
-                                    interpret=interpret, bn=bn,
+        return shard_ops.shard_spmm(plan, x, y=y, mesh=mesh, bn=bn,
                                     pipeline=pipeline)
-    return shard_ops.shard_spmv(plan, x, y=y, mesh=mesh,
-                                interpret=interpret, pipeline=pipeline)
+    return shard_ops.shard_spmv(plan, x, y=y, mesh=mesh, pipeline=pipeline)
 
 
 def _resolve_fused(pm: PackedMatrix, fused) -> bool:
@@ -195,9 +217,12 @@ def _resolve_fused(pm: PackedMatrix, fused) -> bool:
     return bool(fused)
 
 
-def spmv(mat: CSRdtANS | PackedMatrix, x, y=None, *,
-         interpret: bool = True, mesh=None, n_shards=None,
-         pipeline: bool = False, fused=None) -> jax.Array:
+def _add_y(out, y):
+    return out if y is None else out + jnp.asarray(y, dtype=out.dtype)
+
+
+def spmv(mat: CSRdtANS | PackedMatrix, x, y=None, *, mesh=None,
+         n_shards=None, pipeline: bool = False, fused=None) -> jax.Array:
     """y = A x + y with on-the-fly dtANS decoding (fused Pallas kernel).
 
     With ``mesh=`` (model axis > 1) or ``n_shards= > 1`` the matrix is
@@ -211,26 +236,19 @@ def spmv(mat: CSRdtANS | PackedMatrix, x, y=None, *,
     flag).  Both preserve bit-identity (docs/kernels.md)."""
     k = _resolve_shards(mesh, n_shards)
     if k > 1:
-        return _sharded_dtans(mat, x, y, mesh=mesh, k=k,
-                              interpret=interpret, spmm=False,
+        return _sharded_dtans(mat, x, y, mesh=mesh, k=k, spmm=False,
                               pipeline=pipeline)
     pm = get_packed(mat) if isinstance(mat, CSRdtANS) else mat
     shared = _resolve_fused(pm, fused)
-    dt = _out_dtype(pm)
+    dt = out_dtype(pm)
     m, n = pm.shape
+    check_compiled("dtans_spmv", pm.dtype, n, pm.lanes)
     _record_pass("dtans_spmv", pm, n, m, 1, pm.dtype.itemsize,
                  decodes=True)
-    x = jnp.asarray(x, dtype=dt)
-    acc = dtans_spmv_pallas(
-        jnp.asarray(pm.stream), jnp.asarray(pm.esc), jnp.asarray(pm.ns),
-        jnp.asarray(pm.nnz), _tabs(pm), x,
-        params=pm.params, pattern=pm.pattern, max_nseg=pm.max_nseg,
-        lane_width=pm.lane_width, out_dtype=dt, interpret=interpret,
-        pipeline=pipeline, shared_cols=shared)
-    out = acc.reshape(-1)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=dt)
-    return out
+    acc = dtans_spmv_pallas(packed_arrays(pm), jnp.asarray(x, dtype=dt),
+                            pipeline=pipeline, shared_cols=shared,
+                            **_statics(pm))
+    return _add_y(acc[:m], y)
 
 
 def _check_rhs(x, n: int) -> None:
@@ -246,15 +264,11 @@ def _empty_y(m: int, y, dt):
     """B == 0 result: a serving pool with zero active requests is a
     legal input and must not reach the kernels (a zero-size grid
     dimension is not)."""
-    out = jnp.zeros((m, 0), dtype=dt)
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=dt)
-    return out
+    return _add_y(jnp.zeros((m, 0), dtype=dt), y)
 
 
-def spmm(mat: CSRdtANS | PackedMatrix, x, y=None, *,
-         interpret: bool = True, mesh=None, n_shards=None,
-         bn=None, vmem_budget=None, tile_mode: str = "auto",
+def spmm(mat: CSRdtANS | PackedMatrix, x, y=None, *, mesh=None,
+         n_shards=None, bn=None, vmem_budget=None, tile_mode: str = "auto",
          pipeline: bool = False, fused=None) -> jax.Array:
     """Y = A X + Y, X: (n, B) — decode once, contract all B columns in
     the fused kernel. B == 1 runs the single-vector `spmv` kernel, so
@@ -271,195 +285,128 @@ def spmm(mat: CSRdtANS | PackedMatrix, x, y=None, *,
     serial kernel — the conformance suite pins them with exact ==."""
     k = _resolve_shards(mesh, n_shards)
     if k > 1:
-        return _sharded_dtans(mat, x, y, mesh=mesh, k=k,
-                              interpret=interpret, spmm=True, bn=bn,
+        return _sharded_dtans(mat, x, y, mesh=mesh, k=k, spmm=True, bn=bn,
                               pipeline=pipeline)
     pm = get_packed(mat) if isinstance(mat, CSRdtANS) else mat
     shared = _resolve_fused(pm, fused)
-    dt = _out_dtype(pm)
+    dt = out_dtype(pm)
     m, n = pm.shape
     x = jnp.asarray(x, dtype=dt)
     _check_rhs(x, n)
     if x.shape[1] == 0:
         return _empty_y(m, y, dt)
     if x.shape[1] == 1:
-        out = spmv(pm, x[:, 0], interpret=interpret, pipeline=pipeline,
-                   fused=fused)[:, None]
-    else:
-        B = x.shape[1]
-        bn_eff = _resolve_bn(n, pm.lane_width, B, pm.dtype.itemsize,
-                             bn, vmem_budget)
-        _record_pass("dtans_spmm", pm, n, m, B, pm.dtype.itemsize,
-                     decodes=True, col_tiles=_n_tiles(B, bn_eff))
-        acc = dtans_spmm_pallas(
-            jnp.asarray(pm.stream), jnp.asarray(pm.esc), jnp.asarray(pm.ns),
-            jnp.asarray(pm.nnz), _tabs(pm), x,
-            params=pm.params, pattern=pm.pattern, max_nseg=pm.max_nseg,
-            lane_width=pm.lane_width, out_dtype=dt, interpret=interpret,
-            bn=bn_eff, tile_mode=tile_mode, pipeline=pipeline,
-            shared_cols=shared)
-        out = acc.reshape(-1, B)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=dt)
-    return out
+        return _add_y(spmv(pm, x[:, 0], pipeline=pipeline,
+                           fused=fused)[:, None], y)
+    check_compiled("dtans_spmm", pm.dtype, n, pm.lanes)
+    B = x.shape[1]
+    bn_eff = _resolve_bn(n, pm.lane_width, B, pm.dtype.itemsize, bn,
+                         vmem_budget)
+    _record_pass("dtans_spmm", pm, n, m, B, pm.dtype.itemsize,
+                 decodes=True, col_tiles=_n_tiles(B, bn_eff))
+    acc = dtans_spmm_pallas(packed_arrays(pm), x, bn=bn_eff,
+                            tile_mode=tile_mode, pipeline=pipeline,
+                            shared_cols=shared, **_statics(pm))
+    return _add_y(acc[:m], y)
 
 
-def decode(mat: CSRdtANS | PackedMatrix, *, interpret: bool = True):
+def decode(mat: CSRdtANS | PackedMatrix):
     """Decompress to padded (S, L, max_nnz) (cols, vals); cols==-1 pads."""
     pm = get_packed(mat) if isinstance(mat, CSRdtANS) else mat
-    dt = _out_dtype(pm)
+    check_compiled("dtans_decode", pm.dtype, 0, pm.lanes)
     obs.default_registry().counter("kernels.decode_invocations").add(1)
-    return dtans_decode_pallas(
-        jnp.asarray(pm.stream), jnp.asarray(pm.esc), jnp.asarray(pm.ns),
-        jnp.asarray(pm.nnz), _tabs(pm),
-        params=pm.params, pattern=pm.pattern, max_nseg=pm.max_nseg,
-        lane_width=pm.lane_width, out_dtype=dt, interpret=interpret)
+    cols, vals = dtans_decode_pallas(packed_arrays(pm),
+                                     out_dtype=out_dtype(pm),
+                                     **_statics(pm))
+    return slice_major(pm, cols), slice_major(pm, vals)
 
 
-def sell_spmv(ps: PackedSELL, x, y=None, *,
-              interpret: bool = True) -> jax.Array:
+def _plain_spmm(kind: str, packed, x, y, *, rows: int, run, bn=None,
+                vmem_budget=None):
+    """Shared body of the uncoded multi-RHS entry points: ``run(x, bn)``
+    calls the family's kernel on ``x: (n, B)``; B == 1 is the spmv
+    pass (the same kernel at one column)."""
+    m, n = packed.shape
+    dt = packed.values.dtype
+    x = jnp.asarray(x, dtype=dt)
+    _check_rhs(x, n)
+    if x.shape[1] == 0:
+        return _empty_y(m, y, x.dtype)
+    check_compiled(kind, dt, n)
+    B = x.shape[1]
+    if B == 1:
+        _record_pass(f"{kind}_spmv", packed, n, m, 1, dt.itemsize)
+        return _add_y(run(x, None)[:m], y)
+    bn_eff = _resolve_bn(n, rows, B, dt.itemsize, bn, vmem_budget)
+    _record_pass(f"{kind}_spmm", packed, n, m, B, dt.itemsize,
+                 col_tiles=_n_tiles(B, bn_eff))
+    return _add_y(run(x, bn_eff)[:m], y)
+
+
+def sell_spmv(ps: PackedSELL, x, y=None) -> jax.Array:
     """Baseline SELL SpMVM: y = A x + y.
 
     Same ``(mat, x, y=None)`` signature as `spmv` / `rgcsr_spmv` — the
     timing harness (`repro.autotune.measure`) and the conformance suite
     drive all three entry points interchangeably."""
-    m, _ = ps.shape
-    _record_pass("sell_spmv", ps, ps.shape[1], m, 1,
-                 ps.values.dtype.itemsize)
-    acc = sell_spmv_pallas(jnp.asarray(ps.indices), jnp.asarray(ps.values),
-                           jnp.asarray(x, dtype=ps.values.dtype),
-                           interpret=interpret)
-    out = acc.reshape(-1)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=out.dtype)
-    return out
+    return sell_spmm(ps, jnp.asarray(x)[:, None],
+                     None if y is None else jnp.asarray(y)[:, None])[:, 0]
 
 
-def sell_spmm(ps: PackedSELL, x, y=None, *, interpret: bool = True,
-              bn=None, vmem_budget=None,
+def sell_spmm(ps: PackedSELL, x, y=None, *, bn=None, vmem_budget=None,
               tile_mode: str = "auto") -> jax.Array:
     """Multi-RHS SELL: Y = A X + Y, X: (n, B). Shares the `spmm`
-    signature; B == 1 delegates to `sell_spmv` (bit-identical).
+    signature; B == 1 is bit-identical to `sell_spmv`.
     ``bn`` / ``vmem_budget`` / ``tile_mode`` column-tile the B axis
     exactly as in `spmm` (bit-identical at every tile width)."""
-    m, n = ps.shape
-    x = jnp.asarray(x, dtype=ps.values.dtype)
-    _check_rhs(x, n)
-    if x.shape[1] == 0:
-        return _empty_y(m, y, x.dtype)
-    if x.shape[1] == 1:
-        out = sell_spmv(ps, x[:, 0], interpret=interpret)[:, None]
-    else:
-        B = x.shape[1]
-        bn_eff = _resolve_bn(n, ps.lane_width, B,
-                             ps.values.dtype.itemsize, bn, vmem_budget)
-        _record_pass("sell_spmm", ps, n, m, B,
-                     ps.values.dtype.itemsize,
-                     col_tiles=_n_tiles(B, bn_eff))
-        acc = sell_spmm_pallas(jnp.asarray(ps.indices),
-                               jnp.asarray(ps.values), x,
-                               interpret=interpret, bn=bn_eff,
-                               tile_mode=tile_mode)
-        out = acc.reshape(-1, B)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=out.dtype)
-    return out
+    idx, val = jnp.asarray(ps.indices), jnp.asarray(ps.values)
+    return _plain_spmm(
+        "sell", ps, x, y, rows=ps.lane_width, bn=bn,
+        vmem_budget=vmem_budget,
+        run=lambda x, bn: sell_spmm_pallas(idx, val, x, bn=bn,
+                                           tile_mode=tile_mode))
 
 
-def rgcsr_spmv(pr: PackedRGCSR, x, y=None, *,
-               interpret: bool = True) -> jax.Array:
+def rgcsr_spmv(pr: PackedRGCSR, x, y=None) -> jax.Array:
     """Row-grouped CSR SpMVM: y = A x + y (delta prefix-sum in kernel).
 
     Shares the `spmv` / `sell_spmv` signature; see `sell_spmv`."""
-    m, _ = pr.shape
-    _record_pass("rgcsr_spmv", pr, pr.shape[1], m, 1,
-                 pr.values.dtype.itemsize)
-    acc = rgcsr_spmv_pallas(jnp.asarray(pr.deltas), jnp.asarray(pr.values),
-                            jnp.asarray(pr.nnz),
-                            jnp.asarray(x, dtype=pr.values.dtype),
-                            interpret=interpret)
-    out = acc.reshape(-1)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=out.dtype)
-    return out
+    return rgcsr_spmm(pr, jnp.asarray(x)[:, None],
+                      None if y is None else jnp.asarray(y)[:, None])[:, 0]
 
 
-def rgcsr_spmm(pr: PackedRGCSR, x, y=None, *, interpret: bool = True,
-               bn=None, vmem_budget=None,
+def rgcsr_spmm(pr: PackedRGCSR, x, y=None, *, bn=None, vmem_budget=None,
                tile_mode: str = "auto") -> jax.Array:
     """Multi-RHS RGCSR: Y = A X + Y, X: (n, B). Shares the `spmm`
-    signature; B == 1 delegates to `rgcsr_spmv` (bit-identical).
+    signature; B == 1 is bit-identical to `rgcsr_spmv`.
     ``bn`` / ``vmem_budget`` / ``tile_mode`` column-tile the B axis
     exactly as in `spmm` (bit-identical at every tile width)."""
-    m, n = pr.shape
-    x = jnp.asarray(x, dtype=pr.values.dtype)
-    _check_rhs(x, n)
-    if x.shape[1] == 0:
-        return _empty_y(m, y, x.dtype)
-    if x.shape[1] == 1:
-        out = rgcsr_spmv(pr, x[:, 0], interpret=interpret)[:, None]
-    else:
-        B = x.shape[1]
-        bn_eff = _resolve_bn(n, pr.group_size, B,
-                             pr.values.dtype.itemsize, bn, vmem_budget)
-        _record_pass("rgcsr_spmm", pr, n, m, B,
-                     pr.values.dtype.itemsize,
-                     col_tiles=_n_tiles(B, bn_eff))
-        acc = rgcsr_spmm_pallas(jnp.asarray(pr.deltas),
-                                jnp.asarray(pr.values),
-                                jnp.asarray(pr.nnz), x,
-                                interpret=interpret, bn=bn_eff,
-                                tile_mode=tile_mode)
-        out = acc.reshape(-1, B)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=out.dtype)
-    return out
+    arrs = (jnp.asarray(pr.deltas), jnp.asarray(pr.values),
+            jnp.asarray(pr.nnz))
+    return _plain_spmm(
+        "rgcsr", pr, x, y, rows=pr.group_size, bn=bn,
+        vmem_budget=vmem_budget,
+        run=lambda x, bn: rgcsr_spmm_pallas(*arrs, x, bn=bn,
+                                            tile_mode=tile_mode))
 
 
-def bcsr_spmv(pb: PackedBCSR, x, y=None, *,
-              interpret: bool = True) -> jax.Array:
+def bcsr_spmv(pb: PackedBCSR, x, y=None) -> jax.Array:
     """Blocked-CSR SpMVM: y = A x + y (dense r x c tiles in kernel).
 
     Shares the `spmv` / `sell_spmv` signature; see `sell_spmv`."""
-    m, _ = pb.shape
-    _record_pass("bcsr_spmv", pb, pb.shape[1], m, 1,
-                 pb.values.dtype.itemsize)
-    acc = bcsr_spmv_pallas(jnp.asarray(pb.block_cols),
-                           jnp.asarray(pb.values),
-                           jnp.asarray(x, dtype=pb.values.dtype),
-                           interpret=interpret)
-    out = acc.reshape(-1)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=out.dtype)
-    return out
+    return bcsr_spmm(pb, jnp.asarray(x)[:, None],
+                     None if y is None else jnp.asarray(y)[:, None])[:, 0]
 
 
-def bcsr_spmm(pb: PackedBCSR, x, y=None, *, interpret: bool = True,
-              bn=None, vmem_budget=None,
+def bcsr_spmm(pb: PackedBCSR, x, y=None, *, bn=None, vmem_budget=None,
               tile_mode: str = "auto") -> jax.Array:
     """Multi-RHS BCSR: Y = A X + Y, X: (n, B). Shares the `spmm`
-    signature; B == 1 delegates to `bcsr_spmv` (bit-identical).
+    signature; B == 1 is bit-identical to `bcsr_spmv`.
     ``bn`` / ``vmem_budget`` / ``tile_mode`` column-tile the B axis
     exactly as in `spmm` (bit-identical at every tile width)."""
-    m, n = pb.shape
-    x = jnp.asarray(x, dtype=pb.values.dtype)
-    _check_rhs(x, n)
-    if x.shape[1] == 0:
-        return _empty_y(m, y, x.dtype)
-    if x.shape[1] == 1:
-        out = bcsr_spmv(pb, x[:, 0], interpret=interpret)[:, None]
-    else:
-        B = x.shape[1]
-        bn_eff = _resolve_bn(n, pb.block_shape[0], B,
-                             pb.values.dtype.itemsize, bn, vmem_budget)
-        _record_pass("bcsr_spmm", pb, n, m, B,
-                     pb.values.dtype.itemsize,
-                     col_tiles=_n_tiles(B, bn_eff))
-        acc = bcsr_spmm_pallas(jnp.asarray(pb.block_cols),
-                               jnp.asarray(pb.values), x,
-                               interpret=interpret, bn=bn_eff,
-                               tile_mode=tile_mode)
-        out = acc.reshape(-1, B)[:m]
-    if y is not None:
-        out = out + jnp.asarray(y, dtype=out.dtype)
-    return out
+    cols, val = jnp.asarray(pb.block_cols), jnp.asarray(pb.values)
+    return _plain_spmm(
+        "bcsr", pb, x, y, rows=pb.block_shape[0], bn=bn,
+        vmem_budget=vmem_budget,
+        run=lambda x, bn: bcsr_spmm_pallas(cols, val, x, bn=bn,
+                                           tile_mode=tile_mode))

@@ -1,11 +1,21 @@
 """Pack a CSRdtANS matrix into dense, kernel-ready tensors.
 
 The production format stores one flat stream with per-slice offsets. The
-Pallas kernel wants *static* block shapes, so we pad every slice's stream
-(and escape stream) to the matrix-wide maximum and expose them as
-(n_slices, max_*) tensors. The padding is address padding only — it is NOT
-counted in the format's compressed size (CSRdtANS.nbytes), exactly like the
-paper's kernels, which DMA whole cache lines regardless of stream length.
+Pallas kernel wants *static* block shapes that meet the TPU's (8, 128)
+tiling, so the pack groups ``P`` consecutive slices per kernel program
+(`repro.kernels.common.slices_per_program`: 128 lanes in all when the
+lane width divides 128), cuts each group's contiguous run of the flat
+stream (and of every escape stream) into rows of 128 words, and pads the
+rows to the matrix-wide maximum, a multiple of 8.  Each lane carries its
+slice's word offset inside its group (``stream_base`` / ``esc_base``).
+The padding is address padding only — it is NOT counted in the format's
+compressed size (CSRdtANS.nbytes), exactly like the paper's kernels,
+which DMA whole cache lines regardless of stream length.
+
+Every integer is 32-bit: stream words are uint32 (they are < 2^32 by
+construction), symbols are uint32 for float32 matrices (float64 symbols
+need uint64 and run on CPU only), and a slot's digit, base and escape
+flag pack into one uint32 ``tab_meta`` word.
 """
 
 from __future__ import annotations
@@ -16,20 +26,22 @@ import numpy as np
 
 from repro.core.csr_dtans import CSRdtANS
 from repro.core.params import DtansParams
+from repro.kernels.common import (LANES, META_BASE_SHIFT, META_ESC_SHIFT,
+                                  slices_per_program)
 
 
 @dataclasses.dataclass
 class PackedMatrix:
-    """Kernel-ready CSR-dtANS. All arrays are numpy; ops.py moves to jnp."""
-    stream: np.ndarray      # (S, Wmax) uint64 (< 2^32)
-    esc: np.ndarray         # (T, S, Emax) uint64
-    ns: np.ndarray          # (S, L) int32 — symbols per lane (2*nnz)
-    nnz: np.ndarray         # (S, L) int32 — nonzeros per lane
-    row_valid: np.ndarray   # (S, L) bool — lane maps to a real row
-    tab_symbol: np.ndarray  # (T, K) uint64
-    tab_digit: np.ndarray   # (T, K) int32
-    tab_base: np.ndarray    # (T, K) int32
-    tab_is_esc: np.ndarray  # (T, K) int32 (0/1)
+    """Kernel-ready CSR-dtANS, G programs of N = P * L lanes. All arrays
+    are numpy; ops.py moves them to jnp."""
+    stream: np.ndarray       # (G, Rw, 128) uint32 — group word streams
+    stream_base: np.ndarray  # (G, 1, N) int32 — lane's slice offset
+    esc: np.ndarray          # (T, G, Re, 128) symbols — escape streams
+    esc_base: np.ndarray     # (T, G, 1, N) int32
+    nsegs: np.ndarray        # (G, 1, N) int32 — segments per lane
+    nnz: np.ndarray          # (G, 1, N) int32 — nonzeros per lane
+    tab_symbol: np.ndarray   # (T, Rk, 128) symbols per slot
+    tab_meta: np.ndarray     # (T, Rk, 128) uint32 digit|base|escape
     pattern: tuple          # static, length l
     params: DtansParams     # static
     shape: tuple
@@ -43,8 +55,42 @@ class PackedMatrix:
     shared_cols: bool = False
 
     @property
-    def n_slices(self) -> int:
-        return int(self.stream.shape[0])
+    def lanes(self) -> int:
+        """Lanes per kernel program (N)."""
+        return int(self.nsegs.shape[2])
+
+
+def _rows(n_words: int) -> int:
+    """Rows of 128 words holding ``n_words``, rounded up to 8 (>= 8)."""
+    rows = -(-int(n_words) // LANES)
+    return max(8, -(-rows // 8) * 8)
+
+
+def _group_rows(flat: np.ndarray, offsets: np.ndarray, starts, dtype):
+    """Cut ``flat`` at group boundaries into ``(G, R, 128)`` rows."""
+    lens = offsets[starts[1:]] - offsets[starts[:-1]]
+    R = _rows(lens.max() if lens.size else 0)
+    out = np.zeros((len(lens), R * LANES), dtype=dtype)
+    for g in range(len(lens)):
+        lo = offsets[starts[g]]
+        out[g, :lens[g]] = flat[lo:lo + lens[g]]
+    return out.reshape(len(lens), R, LANES)
+
+
+def _lane_base(offsets: np.ndarray, starts, S: int, P: int, L: int):
+    """(G, 1, P*L) word offset of each lane's slice within its group."""
+    G = len(starts) - 1
+    sl = np.minimum(np.arange(G * P).reshape(G, P), S)
+    base = offsets[sl] - offsets[starts[:-1]][:, None]
+    return np.repeat(base, L, axis=1).astype(np.int32)[:, None, :]
+
+
+def _table_rows(t: np.ndarray, dtype) -> np.ndarray:
+    T, K = t.shape
+    R = _rows(K)
+    out = np.zeros((T, R * LANES), dtype=dtype)
+    out[:, :K] = t
+    return out.reshape(T, R, LANES)
 
 
 def pack_matrix(mat: CSRdtANS) -> PackedMatrix:
@@ -53,43 +99,50 @@ def pack_matrix(mat: CSRdtANS) -> PackedMatrix:
     T = len(mat.tables)
     l = mat.params.l
     m = mat.shape[0]
+    P = slices_per_program(L)
+    G = max(1, -(-S // P))
+    N = P * L
+    starts = np.minimum(np.arange(G + 1) * P, S)
 
-    w_lens = np.diff(mat.slice_offsets)
-    Wmax = max(int(w_lens.max()) if S else 0, 1)
-    stream = np.zeros((S, Wmax), dtype=np.uint64)
-    for s in range(S):
-        lo, hi = mat.slice_offsets[s], mat.slice_offsets[s + 1]
-        stream[s, :hi - lo] = mat.stream[lo:hi]
+    if mat.params.m_bits >= META_BASE_SHIFT:
+        raise ValueError(f"m_bits={mat.params.m_bits} does not fit the "
+                         f"{META_BASE_SHIFT}-bit digit field of tab_meta")
+    sym_dtype = np.uint64 if np.dtype(mat.dtype) == np.float64 \
+        else np.uint32
+    st = mat.stacked
+    if sym_dtype == np.uint32 and (
+            int(st.symbol.max(initial=0)) >> 32
+            or any(int(e.max(initial=0)) >> 32 for e in mat.esc_streams)):
+        raise ValueError("float32 matrix carries symbols >= 2^32")
 
-    e_lens = np.diff(mat.esc_offsets, axis=0)  # (S, T)
-    Emax = max(int(e_lens.max()) if S else 0, 1)
-    esc = np.zeros((T, S, Emax), dtype=np.uint64)
-    for t in range(T):
-        for s in range(S):
-            lo, hi = mat.esc_offsets[s, t], mat.esc_offsets[s + 1, t]
-            esc[t, s, :hi - lo] = mat.esc_streams[t][lo:hi]
+    stream = _group_rows(mat.stream, mat.slice_offsets, starts, np.uint32)
+    stream_base = _lane_base(mat.slice_offsets, starts, S, P, L)
+    esc = np.stack([_group_rows(mat.esc_streams[t], mat.esc_offsets[:, t],
+                                starts, sym_dtype) for t in range(T)])
+    Re = max(e.shape[1] for e in esc)
+    esc = np.stack([np.pad(e, ((0, 0), (0, Re - e.shape[1]), (0, 0)))
+                    for e in esc])
+    esc_base = np.stack([_lane_base(mat.esc_offsets[:, t], starts, S, P, L)
+                         for t in range(T)])
 
-    nnz = np.zeros((S, L), dtype=np.int32)
-    row_valid = np.zeros((S, L), dtype=bool)
-    for s in range(S):
-        r0, r1 = s * L, min((s + 1) * L, m)
-        nnz[s, :r1 - r0] = mat.row_nnz[r0:r1]
-        row_valid[s, :r1 - r0] = True
-    ns = 2 * nnz
-
-    nsegs = (ns + l - 1) // l
+    nnz = np.zeros(G * N, dtype=np.int32)
+    nnz[:m] = mat.row_nnz
+    nsegs = (2 * nnz + l - 1) // l
     max_nseg = max(int(nsegs.max()) if S else 0, 1)
+
+    meta = (st.digit.astype(np.uint32)
+            | (st.base.astype(np.uint32) << META_BASE_SHIFT)
+            | (st.is_esc.astype(np.uint32) << META_ESC_SHIFT))
 
     return PackedMatrix(
         stream=stream,
+        stream_base=stream_base,
         esc=esc,
-        ns=ns.astype(np.int32),
-        nnz=nnz,
-        row_valid=row_valid,
-        tab_symbol=mat.stacked.symbol.astype(np.uint64),
-        tab_digit=mat.stacked.digit.astype(np.int32),
-        tab_base=mat.stacked.base.astype(np.int32),
-        tab_is_esc=mat.stacked.is_esc.astype(np.int32),
+        esc_base=esc_base,
+        nsegs=nsegs.astype(np.int32).reshape(G, 1, N),
+        nnz=nnz.reshape(G, 1, N),
+        tab_symbol=_table_rows(st.symbol, sym_dtype),
+        tab_meta=_table_rows(meta, np.uint32),
         pattern=tuple(int(p) for p in mat.pattern),
         params=mat.params,
         shape=mat.shape,
@@ -100,3 +153,10 @@ def pack_matrix(mat: CSRdtANS) -> PackedMatrix:
         # duck-typed so pack.py needs no core.bcsr_dtans import.
         shared_cols=getattr(mat, "block_shape", None) is not None,
     )
+
+
+def slice_major(pm: PackedMatrix, a):
+    """(G, N, k) group-major rows -> (S, L, k) slice-major rows."""
+    L = pm.lane_width
+    S = -(-pm.shape[0] // L)
+    return a.reshape(-1, L, a.shape[-1])[:S]

@@ -32,14 +32,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.kernels.bcsr_spmv import (PackedBCSR, bcsr_spmm_pallas,
-                                     bcsr_spmv_pallas)
-from repro.kernels.dtans_spmv import dtans_spmm_pallas, dtans_spmv_pallas
+from repro.kernels.bcsr_spmv import PackedBCSR, bcsr_spmm_pallas
+from repro.kernels.common import LANES
+from repro.kernels.dtans_spmv import dtans_spmm_pallas
 from repro.kernels.pack import PackedMatrix
-from repro.kernels.rgcsr_spmv import (PackedRGCSR, rgcsr_spmm_pallas,
-                                      rgcsr_spmv_pallas)
-from repro.kernels.sell_spmv import (PackedSELL, sell_spmm_pallas,
-                                     sell_spmv_pallas)
+from repro.kernels.rgcsr_spmv import PackedRGCSR, rgcsr_spmm_pallas
+from repro.kernels.sell_spmv import PackedSELL, sell_spmm_pallas
 
 
 def _pad_stack(arrs, fill=0):
@@ -58,9 +56,8 @@ def _pad_stack(arrs, fill=0):
 # Per-family adapters: stack per-shard packs + run one shard's kernel.
 # ``stack`` -> (arrays, static, rows_cap, out_dtype); ``run`` takes the
 # device-local (leading-axis-stripped) arrays and x: (n, B), returns a
-# (rows_cap, B) partial.  B == 1 routes through the spmv kernel, the
-# same delegation `ops.spmm` makes, so sharded spmv stays bit-identical
-# to the single-device spmv kernel.
+# (rows_cap, B) partial — the same kernel `ops.spmm` runs at every B,
+# so sharded results stay bit-identical to the single-device kernels.
 # --------------------------------------------------------------------------
 
 
@@ -70,81 +67,56 @@ def _stack_dtans(packs):
         if (p.lane_width != p0.lane_width or p.params != p0.params
                 or tuple(p.pattern) != tuple(p0.pattern)
                 or p.esc.shape[0] != p0.esc.shape[0]
+                or p.lanes != p0.lanes
                 or p.shared_cols != p0.shared_cols):
             raise ValueError("dtans shards disagree on static layout "
                              "(lane_width / params / tables)")
-    arrays = [_pad_stack([p.stream for p in packs]),
-              _pad_stack([p.esc for p in packs]),
-              _pad_stack([p.ns for p in packs]),
-              _pad_stack([p.nnz for p in packs]),
-              _pad_stack([p.tab_symbol for p in packs]),
-              _pad_stack([p.tab_digit for p in packs]),
-              _pad_stack([p.tab_base for p in packs]),
-              _pad_stack([p.tab_is_esc for p in packs])]
+    arrays = [_pad_stack([getattr(p, f) for p in packs]) for f in (
+        "stream", "stream_base", "esc", "esc_base", "nsegs", "nnz",
+        "tab_symbol", "tab_meta")]
     dt = jnp.float64 if p0.dtype == np.float64 else jnp.float32
     static = dict(params=p0.params, pattern=tuple(p0.pattern),
                   lane_width=int(p0.lane_width),
                   max_nseg=max(int(p.max_nseg) for p in packs),
-                  out_dtype=dt, shared_cols=bool(p0.shared_cols))
-    return arrays, static, arrays[0].shape[1] * p0.lane_width, dt
+                  shared_cols=bool(p0.shared_cols))
+    return arrays, static, arrays[0].shape[1] * p0.lanes, dt
 
 
-def _run_dtans(arrs, x, st, interpret, tile):
-    stream, esc, ns, nnz, sym, dig, base, isesc = arrs
-    tabs = (sym, dig, base, isesc)
-    kw = dict(params=st["params"], pattern=st["pattern"],
-              max_nseg=st["max_nseg"], lane_width=st["lane_width"],
-              out_dtype=st["out_dtype"], interpret=interpret,
-              pipeline=tile["pipeline"],
-              shared_cols=st["shared_cols"])
-    if x.shape[1] == 1:
-        acc = dtans_spmv_pallas(stream, esc, ns, nnz, tabs, x[:, 0], **kw)
-        return acc.reshape(-1)[:, None]
-    acc = dtans_spmm_pallas(stream, esc, ns, nnz, tabs, x, bn=tile["bn"],
-                            tile_mode=tile["tile_mode"], **kw)
-    return acc.reshape(-1, x.shape[1])
+def _run_dtans(arrs, x, st, tile):
+    return dtans_spmm_pallas(tuple(arrs), x, bn=tile["bn"],
+                             tile_mode=tile["tile_mode"],
+                             pipeline=tile["pipeline"], **st)
 
 
 def _stack_sell(packs):
     p0 = packs[0]
-    L = p0.lane_width
-    if any(p.lane_width != L for p in packs):
+    if any(p.lane_width != p0.lane_width for p in packs):
         raise ValueError("sell shards disagree on slice_height")
     arrays = [_pad_stack([p.indices for p in packs], fill=-1),
               _pad_stack([p.values for p in packs])]
-    return arrays, {}, arrays[0].shape[1] * L, p0.values.dtype
+    return arrays, {}, arrays[0].shape[2], p0.values.dtype
 
 
-def _run_sell(arrs, x, st, interpret, tile):
+def _run_sell(arrs, x, st, tile):
     idx, val = arrs
-    if x.shape[1] == 1:
-        return sell_spmv_pallas(idx, val, x[:, 0],
-                                interpret=interpret).reshape(-1)[:, None]
-    return sell_spmm_pallas(idx, val, x, interpret=interpret,
-                            bn=tile["bn"], tile_mode=tile["tile_mode"]
-                            ).reshape(-1, x.shape[1])
+    return sell_spmm_pallas(idx, val, x, bn=tile["bn"],
+                            tile_mode=tile["tile_mode"])
 
 
 def _stack_rgcsr(packs):
     p0 = packs[0]
-    G = p0.group_size
-    if any(p.group_size != G for p in packs):
+    if any(p.group_size != p0.group_size for p in packs):
         raise ValueError("rgcsr shards disagree on group_size")
     arrays = [_pad_stack([p.deltas for p in packs]),
               _pad_stack([p.values for p in packs]),
               _pad_stack([p.nnz for p in packs])]
-    return arrays, {}, arrays[0].shape[1] * G, p0.values.dtype
+    return arrays, {}, arrays[0].shape[2], p0.values.dtype
 
 
-def _run_rgcsr(arrs, x, st, interpret, tile):
+def _run_rgcsr(arrs, x, st, tile):
     deltas, val, nnz = arrs
-    if x.shape[1] == 1:
-        return rgcsr_spmv_pallas(deltas, val, nnz, x[:, 0],
-                                 interpret=interpret
-                                 ).reshape(-1)[:, None]
-    return rgcsr_spmm_pallas(deltas, val, nnz, x, interpret=interpret,
-                             bn=tile["bn"], tile_mode=tile["tile_mode"]
-                             ).reshape(-1, x.shape[1])
+    return rgcsr_spmm_pallas(deltas, val, nnz, x, bn=tile["bn"],
+                             tile_mode=tile["tile_mode"])
 
 
 def _stack_bcsr(packs):
@@ -153,18 +125,13 @@ def _stack_bcsr(packs):
         raise ValueError("bcsr shards disagree on block_shape")
     arrays = [_pad_stack([p.block_cols for p in packs], fill=-1),
               _pad_stack([p.values for p in packs])]
-    r = p0.block_shape[0]
-    return arrays, {}, arrays[0].shape[1] * r, p0.values.dtype
+    return arrays, {}, arrays[0].shape[2], p0.values.dtype
 
 
-def _run_bcsr(arrs, x, st, interpret, tile):
+def _run_bcsr(arrs, x, st, tile):
     cols, val = arrs
-    if x.shape[1] == 1:
-        return bcsr_spmv_pallas(cols, val, x[:, 0],
-                                interpret=interpret).reshape(-1)[:, None]
-    return bcsr_spmm_pallas(cols, val, x, interpret=interpret,
-                            bn=tile["bn"], tile_mode=tile["tile_mode"]
-                            ).reshape(-1, x.shape[1])
+    return bcsr_spmm_pallas(cols, val, x, bn=tile["bn"],
+                            tile_mode=tile["tile_mode"])
 
 
 #: packed-artifact type -> (stack, run).  A family (or third-party
@@ -213,19 +180,14 @@ def _tile_opts(bn=None, tile_mode="auto", pipeline=False):
     return dict(bn=bn, tile_mode=tile_mode, pipeline=bool(pipeline))
 
 
-def _loop_spmm(plan, x2, *, interpret: bool, tile):
+def _loop_spmm(plan, x2, *, tile):
     """Sequential fallback: every shard in turn on one device, rows
     concatenated — no mesh needed, every registered format supported.
 
     Kernel-backed families run through the SAME stacked adapters as the
-    collective path: stacking pads each shard's tensors to the
-    fleet-wide max, which equals the full-matrix pack's padded widths
-    (the global max row/group/segment lives in some shard), so kernels
-    that tree-reduce over the padded width axis (SELL/RGCSR) see the
-    single-device reduction tree exactly — a shard's own narrower pack
-    would round differently at the last ulp.  Other formats go through
-    their registry `spmm_runner` per shard (their row results are
-    width-independent)."""
+    collective path (the kernels accumulate in stored-position order, so
+    the extra padding of the stack adds only trailing zeros).  Other
+    formats go through their registry `spmm_runner` per shard."""
     zero_dt = jnp.float64 if plan.dtype == np.float64 else jnp.float32
     blocks = []
     if supports_shard_map(plan):
@@ -238,7 +200,7 @@ def _loop_spmm(plan, x2, *, interpret: bool, tile):
             if rows == 0:
                 continue                  # empty shard: zero rows
             local = [jnp.asarray(a[k]) for a in arrays]
-            blocks.append(run(local, xj, static, interpret, tile)[:rows])
+            blocks.append(run(local, xj, static, tile)[:rows])
     else:
         from repro.sparse.registry import get_format
         spec = get_format(plan.fmt)
@@ -246,21 +208,34 @@ def _loop_spmm(plan, x2, *, interpret: bool, tile):
             if plan.boundaries[k + 1] == plan.boundaries[k]:
                 continue
             blocks.append(jnp.asarray(spec.spmm_runner(
-                plan.shards[k], x2, interpret=interpret)()))
+                plan.shards[k], x2)()))
     if not blocks:
         return jnp.zeros((0, x2.shape[1]), zero_dt)
     return jnp.concatenate(blocks, axis=0)
 
 
-def _shard_map_spmm(plan, x2, mesh, *, interpret: bool, tile):
+def place_on_mesh(plan, mesh):
+    """The plan's stacked shard tensors placed over the mesh ``model``
+    axis — each device holds its own shard only — with the adapter's
+    ``(static, rows_cap, dtype)``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    stack, _ = SHARD_MAP_ADAPTERS[type(plan.shards[0])]
+    arrays, static, rows_cap, dt = stack(plan.shards)
+    placed = [jax.device_put(a, NamedSharding(
+        mesh, P("model", *([None] * (a.ndim - 1))))) for a in arrays]
+    return placed, static, rows_cap, dt
+
+
+def _shard_map_spmm(plan, x2, mesh, *, tile):
     """The collective path: stacked shard tensors sharded over the mesh
     ``model`` axis, x broadcast (replicated in-spec), per-device kernel,
     row-masked partials placed at each shard's row offset, psum."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    stack, run = SHARD_MAP_ADAPTERS[type(plan.shards[0])]
-    arrays, static, rows_cap, dt = stack(plan.shards)
+    _, run = SHARD_MAP_ADAPTERS[type(plan.shards[0])]
+    arrs, static, rows_cap, dt = place_on_mesh(plan, mesh)
     dt = jnp.float64 if np.dtype(dt) == np.float64 else jnp.float32
     m, _ = plan.shape
     B = x2.shape[1]
@@ -268,11 +243,10 @@ def _shard_map_spmm(plan, x2, mesh, *, interpret: bool, tile):
     rows = np.asarray(np.diff(np.asarray(plan.boundaries)), np.int32)
     m_pad = max(m, int(r0.max()) + rows_cap)
     xj = jnp.asarray(x2, dtype=dt)
-    arrs = [jnp.asarray(a) for a in arrays]
 
     def body(r0_k, rows_k, x, *arrs_k):
         local = [a[0] for a in arrs_k]
-        part = run(local, x, static, interpret, tile).astype(dt)
+        part = run(local, x, static, tile).astype(dt)
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows_cap, 1), 0)
         part = jnp.where(lane < rows_k[0], part, 0)
         out = jnp.zeros((m_pad, B), dt)
@@ -282,8 +256,8 @@ def _shard_map_spmm(plan, x2, mesh, *, interpret: bool, tile):
 
     specs = [P("model"), P("model"), P(None, None)] + \
         [P("model", *([None] * (a.ndim - 1))) for a in arrs]
-    f = shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                  out_specs=P(None, None), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                      out_specs=P(None, None), check_vma=False)
     return f(jnp.asarray(r0), jnp.asarray(rows), xj, *arrs)[:m]
 
 
@@ -297,8 +271,8 @@ def _validate_mesh(plan, mesh):
             f"n_shards=model_axis_size(mesh)")
 
 
-def shard_spmm(plan, x, y=None, *, mesh=None, interpret: bool = True,
-               bn=None, tile_mode: str = "auto",
+def shard_spmm(plan, x, y=None, *, mesh=None, bn=None,
+               tile_mode: str = "auto",
                pipeline: bool = False) -> jax.Array:
     """Y = A X + Y from a shard plan, X: (n, B) — the sharded analogue
     of `ops.spmm`.  With a mesh (model axis == ``plan.n_shards``) and a
@@ -317,6 +291,10 @@ def shard_spmm(plan, x, y=None, *, mesh=None, interpret: bool = True,
         raise ValueError(f"shard_spmm rhs has {x2.shape[0]} rows; "
                          f"matrix has {n} columns")
     dt = jnp.float64 if plan.dtype == np.float64 else jnp.float32
+    if plan.shards:
+        from repro.kernels.ops import check_compiled
+        check_compiled("shard_spmm", plan.dtype, n,
+                       getattr(plan.shards[0], "lanes", LANES))
     if x2.shape[1] == 0 or m == 0:
         out = jnp.zeros((m, x2.shape[1]), dt)
     else:
@@ -327,16 +305,15 @@ def shard_spmm(plan, x, y=None, *, mesh=None, interpret: bool = True,
         _record_shard_pass(plan, x2.shape[1], collective=collective)
         tile = _tile_opts(bn=bn, tile_mode=tile_mode, pipeline=pipeline)
         if collective:
-            out = _shard_map_spmm(plan, x2, mesh, interpret=interpret,
-                                  tile=tile)
+            out = _shard_map_spmm(plan, x2, mesh, tile=tile)
         else:
-            out = _loop_spmm(plan, x2, interpret=interpret, tile=tile)
+            out = _loop_spmm(plan, x2, tile=tile)
     if y is not None:
         out = out + jnp.asarray(y, dtype=out.dtype)
     return out
 
 
-def shard_spmv(plan, x, y=None, *, mesh=None, interpret: bool = True,
+def shard_spmv(plan, x, y=None, *, mesh=None,
                pipeline: bool = False) -> jax.Array:
     """y = A x + y from a shard plan, 1-D ``x`` — the sharded analogue
     of `ops.spmv`.  Routes through the spmv kernels (B == 1), so the
@@ -344,8 +321,7 @@ def shard_spmv(plan, x, y=None, *, mesh=None, interpret: bool = True,
     x1 = jnp.asarray(x)
     if x1.ndim != 1:
         raise ValueError(f"shard_spmv expects 1-D x; got {x1.shape}")
-    out = shard_spmm(plan, x1[:, None], mesh=mesh,
-                     interpret=interpret, pipeline=pipeline)[:, 0]
+    out = shard_spmm(plan, x1[:, None], mesh=mesh, pipeline=pipeline)[:, 0]
     if y is not None:
         out = out + jnp.asarray(y, dtype=out.dtype)
     return out

@@ -98,62 +98,80 @@ def resolve_tile_mode(tile_mode: str, interpret: bool) -> str:
     return tile_mode
 
 
-def blocked_spmm(kernel, mat_args, mat_specs, x, *, rows: int,
-                 out_dtype, grid_s: int, bn: int | None,
-                 tile_mode: str = "auto", interpret: bool = True):
+def blocked_spmm(kernel, mat_args, mat_specs, x, *, lanes: int,
+                 grid_s: int, bn: int | None, tile_mode: str = "auto",
+                 interpret: bool | None = None):
     """Run a family's SpMM kernel over ``bn``-column tiles of ``x``.
 
     ``mat_specs`` is a list of ``(block_shape, index_map)`` pairs for
-    the matrix operands, with 1-D (slice-only) index maps — the helper
-    lifts them to the 2-D grid itself.  ``rows`` is the per-program
-    output row count (lane width / group size / block height), so the
-    result is ``(grid_s, rows, B)`` exactly like the untiled wrappers.
+    the matrix operands, with 1-D (program-only) index maps — the helper
+    lifts them to the 2-D grid itself.  The kernel sees ``x`` transposed,
+    as a ``(Bt, n)`` tile, and writes a ``(Bt, lanes)`` block of output
+    rows; the result is ``(grid_s * lanes, B)`` rows.
 
-    ``bn=None`` (or ``bn >= B``) is the untiled single-tile call — the
-    same pallas_call the PR-5 kernels made, so the default path pays
-    nothing for the tiling machinery.
+    ``bn=None`` (or ``bn >= B``) is the untiled single-tile call.  A
+    tile is a multiple of 8 columns (the TPU sublane tiling), so ``bn``
+    rounds up to one.  ``interpret=None`` follows the backend
+    (`repro.backend.interpret_mode`).
     """
+    from repro.backend import interpret_mode
+    from repro.kernels.common import kernel_x64
+
+    if interpret is None:
+        interpret = interpret_mode()
+    with kernel_x64(interpret):
+        return _blocked_spmm(kernel, mat_args, mat_specs, x, lanes=lanes,
+                             grid_s=grid_s, bn=bn, tile_mode=tile_mode,
+                             interpret=interpret)
+
+
+def _blocked_spmm(kernel, mat_args, mat_specs, x, *, lanes, grid_s, bn,
+                  tile_mode, interpret):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    from repro.kernels.common import LANES, X_CHUNK
+
     n, B = x.shape
     S = int(grid_s)
+    xt = x.T                                              # (B, n)
+    if n > X_CHUNK and n % LANES:   # gather_mul slices x in 128-aligned chunks
+        xt = jnp.pad(xt, ((0, 0), (0, LANES - n % LANES)))
+        n = xt.shape[1]
 
-    def call(xt, bt):
-        in_specs = [pl.BlockSpec(shape, fn) for shape, fn in mat_specs]
-        in_specs.append(pl.BlockSpec((n, bt), lambda s: (0, 0)))
+    def call(xt, bt, J):
+        if J == 1:
+            lift = lambda f: f                            # noqa: E731
+            x_spec = pl.BlockSpec((bt, n), lambda s: (0, 0))
+            out_spec = pl.BlockSpec((None, bt, lanes), lambda s: (s, 0, 0))
+        else:
+            lift = lambda f: lambda s, j: f(s)            # noqa: E731
+            x_spec = pl.BlockSpec((bt, n), lambda s, j: (j, 0))
+            out_spec = pl.BlockSpec((None, bt, lanes),
+                                    lambda s, j: (s, j, 0))
+        in_specs = [pl.BlockSpec(shape, lift(fn)) for shape, fn in mat_specs]
         return pl.pallas_call(
             kernel,
-            grid=(S,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, rows, bt), lambda s: (s, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((S, rows, bt), out_dtype),
+            grid=(S,) if J == 1 else (S, J),
+            in_specs=in_specs + [x_spec],
+            out_specs=out_spec,
+            out_shape=jax.ShapeDtypeStruct((S, xt.shape[0], lanes),
+                                           xt.dtype),
             interpret=interpret,
         )(*mat_args, xt)
 
-    if bn is None or int(bn) >= B:
-        return call(x, B)
-    bn = int(bn)
-    mode = resolve_tile_mode(tile_mode, interpret)
-    J = -(-B // bn)
-    if B % bn:
-        x = jnp.pad(x, ((0, 0), (0, J * bn - B)))
-    if mode == "loop":
-        xt = jnp.moveaxis(x.reshape(n, J, bn), 1, 0)      # (J, n, bn)
-        ys = jax.lax.map(lambda xj: call(xj, bn), xt)     # (J, S, rows, bn)
-        return jnp.moveaxis(ys, 0, 2).reshape(S, rows, J * bn)[:, :, :B]
-    # 2-D grid: lift the slice-only index maps to (s, j) arity; the x
-    # spec walks the column blocks and the out spec scatters per tile.
-    in_specs = [pl.BlockSpec(shape, (lambda f: lambda s, j: f(s))(fn))
-                for shape, fn in mat_specs]
-    in_specs.append(pl.BlockSpec((n, bn), lambda s, j: (0, j)))
-    out = pl.pallas_call(
-        kernel,
-        grid=(S, J),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, bn), lambda s, j: (s, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((S, rows, J * bn), out_dtype),
-        interpret=interpret,
-    )(*mat_args, x)
-    return out[:, :, :B]
+    bn = None if bn is None else -(-int(bn) // 8) * 8
+    if bn is None or bn >= B:
+        y = call(xt, B, 1)
+    else:
+        J = -(-B // bn)
+        if B % bn:
+            xt = jnp.pad(xt, ((0, J * bn - B), (0, 0)))
+        if resolve_tile_mode(tile_mode, interpret) == "loop":
+            ys = jax.lax.map(lambda xj: call(xj, bn, 1),
+                             xt.reshape(J, bn, n))        # (J, S, bn, N)
+            y = jnp.moveaxis(ys, 0, 1).reshape(S, J * bn, lanes)
+        else:
+            y = call(xt, bn, J)
+    return jnp.transpose(y[:, :B, :], (0, 2, 1)).reshape(S * lanes, B)

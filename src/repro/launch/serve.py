@@ -15,6 +15,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.backend import device_label, enable_compilation_cache
 from repro.models import api
 from repro.serving.engine import Engine
 
@@ -33,6 +34,7 @@ def main():
                          "its compression (paper technique)")
     ap.add_argument("--sparsity", type=float, default=0.8)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
@@ -57,9 +59,12 @@ def main():
     eng.run_until_drained()
     dt = time.time() - t0
     toks = sum(len(r.out) for r in reqs)
+    dev = device_label()
+    mode = "interpreted" if dev["interpret"] else "compiled"
     print(f"served {sum(r.done for r in reqs)}/{len(reqs)} requests, "
           f"{toks} tokens in {dt:.1f}s ({toks/max(dt,1e-9):.1f} tok/s, "
-          f"CPU interpret)")
+          f"{dev['platform']} {dev['kind']} x{dev['count']}, "
+          f"kernels {mode})")
 
 
 if __name__ == "__main__":

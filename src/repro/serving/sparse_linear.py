@@ -147,8 +147,7 @@ class SparseLinear:
     def compression_vs_best_sparse(self) -> float:
         return self.baseline_bytes / self.mat.nbytes
 
-    def apply(self, x, *, interpret: bool = True, bn=None,
-              pipeline: bool = False,
+    def apply(self, x, *, bn=None, pipeline: bool = False,
               metrics: obs.MetricsRegistry | None = None):
         """x: (..., d_in) -> (..., d_out).
 
@@ -188,17 +187,18 @@ class SparseLinear:
                 from repro.kernels import shard_ops
                 y = shard_ops.shard_spmm(self.plan, xb.T,
                                          mesh=self.mesh,
-                                         interpret=interpret,
                                          bn=bn, pipeline=pipeline)
             else:
-                y = ops.spmm(self.packed, xb.T, interpret=interpret,
-                             bn=bn, pipeline=pipeline)  # (d_out, B)
+                y = ops.spmm(self.packed, xb.T, bn=bn,
+                             pipeline=pipeline)  # (d_out, B)
         return y.T.reshape(*lead, self.d_out).astype(x.dtype)
 
     def apply_dense_reference(self, x):
-        """Oracle: decode to dense and matmul (tests). Contracts in the
-        matrix dtype, like `apply`."""
+        """Oracle: decode to dense and matmul at HIGHEST precision
+        (tests, chip smoke). Contracts in the matrix dtype, like
+        `apply`."""
         from repro.core.csr_dtans import decode_matrix
         w = decode_matrix(self.mat).to_dense()   # (d_out, d_in)
-        return (jnp.asarray(x, dtype=w.dtype) @ jnp.asarray(w).T
-                ).astype(x.dtype)
+        return jnp.matmul(jnp.asarray(x, dtype=w.dtype), jnp.asarray(w).T,
+                          precision=jax.lax.Precision.HIGHEST
+                          ).astype(x.dtype)

@@ -284,20 +284,19 @@ class FormatSpec:
         """Packed, runnable artifact for matrix ``a``."""
         raise NotImplementedError(f"{self.name}: pack")
 
-    def runner(self, packed, x, *, interpret: bool = True):
+    def runner(self, packed, x):
         """Zero-arg callable computing ``y = A x`` from `pack`'s
         artifact (feed it to `repro.autotune.measure.time_kernel`)."""
         fn = self.spmv_fn
         if fn is None:
             raise NotImplementedError(f"{self.name}: runner")
-        return lambda: fn(packed, x, interpret=interpret)
+        return lambda: fn(packed, x)
 
-    def spmv(self, a, x, *, params: DtansParams = PAPER,
-             interpret: bool = True, **knobs):
+    def spmv(self, a, x, *, params: DtansParams = PAPER, **knobs):
         """One-shot ``y = A x`` through the registered kernel path —
         how the conformance suite drives every format."""
         packed = self.pack(a, params=params, **knobs)
-        return self.runner(packed, x, interpret=interpret)()
+        return self.runner(packed, x)()
 
     # -- multi-RHS (SpMM) --------------------------------------------
 
@@ -311,8 +310,7 @@ class FormatSpec:
         kernel by overriding only this property."""
         return None
 
-    def spmm_runner(self, packed, x, *, interpret: bool = True,
-                    bn=None, tile_mode: str = "auto",
+    def spmm_runner(self, packed, x, *, bn=None, tile_mode: str = "auto",
                     pipeline: bool = False):
         """Zero-arg callable computing ``Y = A X`` (``X: (n, B)``) from
         `pack`'s artifact — the batched analogue of `runner`, driven by
@@ -337,26 +335,26 @@ class FormatSpec:
                 kw["tile_mode"] = tile_mode
             if pipeline:
                 kw["pipeline"] = True
-            return lambda: fn(packed, x, interpret=interpret, **kw)
+            return lambda: fn(packed, x, **kw)
         x2 = np.asarray(x)
         if x2.ndim != 2:
             raise ValueError(f"{self.name}: spmm_runner expects x of "
                              f"shape (n, B); got {x2.shape}")
-        runners = [self.runner(packed, x2[:, b], interpret=interpret)
+        runners = [self.runner(packed, x2[:, b])
                    for b in range(x2.shape[1])]
         import jax.numpy as jnp
         return lambda: jnp.stack([jnp.asarray(r()) for r in runners],
                                  axis=-1)
 
     def spmm(self, a, x, *, params: DtansParams = PAPER,
-             interpret: bool = True, bn=None, tile_mode: str = "auto",
-             pipeline: bool = False, **knobs):
+             bn=None, tile_mode: str = "auto", pipeline: bool = False,
+             **knobs):
         """One-shot ``Y = A X`` through the registered batched kernel
         path — how the conformance suite sweeps every format over B
         (and, with ``bn`` / ``pipeline``, over the tiled and pipelined
         schedules, pinned bit-identical to the plain kernel)."""
         packed = self.pack(a, params=params, **knobs)
-        return self.spmm_runner(packed, x, interpret=interpret, bn=bn,
+        return self.spmm_runner(packed, x, bn=bn,
                                 tile_mode=tile_mode,
                                 pipeline=pipeline)()
 
@@ -407,8 +405,7 @@ class FormatSpec:
                          shard_nbytes=tuple(sizes), shape=a.shape,
                          dtype=np.dtype(a.values.dtype))
 
-    def shard_runner(self, plan, x, *, mesh=None,
-                     interpret: bool = True, bn=None,
+    def shard_runner(self, plan, x, *, mesh=None, bn=None,
                      tile_mode: str = "auto", pipeline: bool = False):
         """Zero-arg callable computing ``y = A x`` (1-D ``x``) or
         ``Y = A X`` (2-D ``x``) from a `shard` plan — the sharded
@@ -424,11 +421,9 @@ class FormatSpec:
         x2 = np.asarray(x)
         if x2.ndim == 1:
             return lambda: shard_ops.shard_spmv(plan, x, mesh=mesh,
-                                                interpret=interpret,
                                                 pipeline=pipeline)
-        return lambda: shard_ops.shard_spmm(plan, x, mesh=mesh,
-                                            interpret=interpret,
-                                            bn=bn, tile_mode=tile_mode,
+        return lambda: shard_ops.shard_spmm(plan, x, mesh=mesh, bn=bn,
+                                            tile_mode=tile_mode,
                                             pipeline=pipeline)
 
     # -- encoded artifact (decodes=True formats) ---------------------
@@ -571,21 +566,20 @@ class DenseSpec(FormatSpec):
     def pack(self, a, *, params=PAPER, artifacts=None, **knobs):
         return a.to_dense()
 
-    def runner(self, packed, x, *, interpret: bool = True):
+    def runner(self, packed, x):
         import jax
         import jax.numpy as jnp
         d = jnp.asarray(packed)
         xj = jnp.asarray(x, dtype=d.dtype)
         return jax.jit(lambda: d @ xj)
 
-    def spmm_runner(self, packed, x, *, interpret: bool = True,
-                    bn=None, tile_mode: str = "auto",
+    def spmm_runner(self, packed, x, *, bn=None, tile_mode: str = "auto",
                     pipeline: bool = False):
         # Dense ``A @ X`` is the same contraction for any number of
         # right-hand sides — the single-vector runner already is the
         # batched bandwidth anchor.  XLA tiles the contraction itself,
         # so the tile knobs are accepted and ignored.
-        return self.runner(packed, x, interpret=interpret)
+        return self.runner(packed, x)
 
 
 class _RowSeqSpec(FormatSpec):
@@ -603,7 +597,7 @@ class _RowSeqSpec(FormatSpec):
     def pack(self, a, *, params=PAPER, artifacts=None, **knobs):
         return a
 
-    def runner(self, packed, x, *, interpret: bool = True):
+    def runner(self, packed, x):
         import jax
         import jax.numpy as jnp
         a = packed
@@ -620,8 +614,7 @@ class _RowSeqSpec(FormatSpec):
 
         return run
 
-    def spmm_runner(self, packed, x, *, interpret: bool = True,
-                    bn=None, tile_mode: str = "auto",
+    def spmm_runner(self, packed, x, *, bn=None, tile_mode: str = "auto",
                     pipeline: bool = False):
         # Batched scatter-add stand-in: one (m, B) accumulator, the
         # same row scatter, every RHS column updated per nonzero.

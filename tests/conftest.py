@@ -22,6 +22,33 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 import pytest  # noqa: E402
 
+#: Each interpret-mode Pallas kernel compiles to an XLA:CPU executable
+#: that holds several hundred memory maps for as long as a jit cache keeps
+#: it.  A module that sweeps many shapes would pass Linux's per-process
+#: map limit (``vm.max_map_count``, 65530 by default) and crash its
+#: worker, so JAX's caches are cleared between tests once the process
+#: holds this many maps.
+MAP_BUDGET = 24000
+
+
+def _map_count() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:          # not Linux: no map limit to guard
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_kernels():
+    yield
+    if _map_count() > MAP_BUDGET:
+        import gc
+
+        import jax
+        jax.clear_caches()
+        gc.collect()
+
 
 @pytest.fixture(scope="session")
 def make_model_mesh():

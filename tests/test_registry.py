@@ -84,7 +84,7 @@ class ToyDiagSpec(FormatSpec):
         d = a.to_dense()
         return np.diagonal(d).copy(), d.shape
 
-    def runner(self, packed, x, *, interpret=True):
+    def runner(self, packed, x):
         diag, shape = packed
         m, n = shape
         k = min(m, n)
