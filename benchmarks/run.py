@@ -16,11 +16,6 @@ Prints ``name,us_per_call,derived`` CSV.
   calib   — MachineModel calibration: fit cost-model constants to
             measured kernel times; ``--profile-json`` persists the
             fitted machine profile (CI uploads it as an artifact)
-  load    — serving load test: seeded Poisson arrivals into the
-            Engine, dense vs compressed LM head (tokens/sec, p50/p99
-            step latency, occupancy, obs-layer overhead); writes
-            ``--bench-serving-json`` (default: BENCH_serving.json at
-            the repo root — the tracked perf trajectory)
   tiles   — kernel tile/pipeline microbench (fig9tile rows): grid-
             blocked SpMM best-tile-config vs untiled over a batch
             sweep, fused BCSR-dtANS block-decode vs the generic
@@ -61,9 +56,6 @@ def main() -> None:
                          ".mtx.gz, e.g. SuiteSparse downloads) fed "
                          "through repro.sparse.io into the fig9 "
                          "selection suite")
-    ap.add_argument("--bench-serving-json", default=None, metavar="PATH",
-                    help="where the load section writes its "
-                         "BENCH_serving.json (default: repo root)")
     ap.add_argument("--max-nnz", default=2_000_000, type=int,
                     help="skip --mtx-dir files with more stored "
                          "nonzeros than this (default 2e6; the "
@@ -76,8 +68,7 @@ def main() -> None:
     from benchmarks import (bench_batch_selection, bench_calibration,
                             bench_compression, bench_delta_entropy,
                             bench_format_selection, bench_kernel_tiles,
-                            bench_serving_load, bench_shard_selection,
-                            bench_spmv)
+                            bench_shard_selection, bench_spmv)
 
     print("name,us_per_call,derived")
     sections = {
@@ -94,10 +85,6 @@ def main() -> None:
         "calib": lambda: bench_calibration.run(
             small=args.small, profile_json=args.profile_json),
         "tiles": lambda: bench_kernel_tiles.run(small=args.small),
-        "load": lambda: bench_serving_load.run(
-            small=args.small,
-            bench_json=args.bench_serving_json
-            or bench_serving_load.DEFAULT_BENCH_JSON),
     }
     only = set(args.only.split(",")) if args.only else None
     collected = []

@@ -139,10 +139,16 @@ def get_packed(mat: CSRdtANS) -> PackedMatrix:
 
 
 def packed_arrays(pm: PackedMatrix):
-    """The kernel operands of a pack, in `PackedMatrix` field order."""
-    return tuple(jnp.asarray(a) for a in (
-        pm.stream, pm.stream_base, pm.esc, pm.esc_base, pm.nsegs, pm.nnz,
-        pm.tab_symbol, pm.tab_meta))
+    """The kernel operands of a pack on the device, in `PackedMatrix`
+    field order. The pack holds them on the host, so every call copies
+    them all (`_packed_nbytes`): counted in ``kernels.h2d_bytes`` and
+    timed as the ``kernels.upload`` span."""
+    nbytes = _packed_nbytes(pm)
+    obs.default_registry().counter("kernels.h2d_bytes").add(nbytes)
+    with obs.span("kernels.upload", bytes=nbytes):
+        return tuple(jnp.asarray(a) for a in (
+            pm.stream, pm.stream_base, pm.esc, pm.esc_base, pm.nsegs,
+            pm.nnz, pm.tab_symbol, pm.tab_meta))
 
 
 def _statics(pm: PackedMatrix) -> dict:

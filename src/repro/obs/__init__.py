@@ -1,4 +1,4 @@
-"""repro.obs: dependency-free metrics + tracing for the serving stack.
+"""repro.obs: metrics and tracing for the serving stack.
 
 The paper's core claim is a *measured* speedup; SMASH makes the same
 point structurally — compression only pays when decode time hides behind
@@ -12,15 +12,17 @@ execution path. This package is that instrumentation layer:
   keeps the quantiles representative at fixed memory. `snapshot()` is
   lock-free — it copies instrument state without stopping writers.
 * **Tracing** (`repro.obs.trace`): a `span()` context manager and
-  `event()` emitter writing JSONL to the path in ``$REPRO_TRACE`` (or
-  `configure_trace(path)`). With no sink configured both are near-free
-  no-ops — the serving engine stays instrumented in production with
-  sub-2% overhead (measured by ``benchmarks.run --only load``).
+  `event()` emitter recording JSONL for the path in ``$REPRO_TRACE``
+  (or `configure_trace(path)`), kept in memory and written when the
+  sink closes. Each span is also a `jax.profiler.TraceAnnotation`, so a
+  profile holds it on the device trace's clock. With no sink
+  configured both cost one predicate check and import nothing.
 
 Instrumented layers: `serving.Engine` (step/prefill/decode/refill wall
-time, tokens/sec, occupancy, queue depth, TTFT, end-to-end latency),
-`serving.SparseLinear` + `kernels.ops` (decode invocations, bytes moved
-per SpMM, batch-size histogram), and `repro.autotune` (decision-cache
+time, occupancy, queue depth, TTFT, end-to-end latency, logits bytes
+copied to the host), `serving.SparseLinear` + `kernels.ops` (decode
+invocations, bytes moved per SpMM and uploaded from the host,
+batch-size histogram), and `repro.autotune` (decision-cache
 hits/misses, timing dispersion, selection events). `docs/observability.md`
 lists every metric name and the trace schema.
 """

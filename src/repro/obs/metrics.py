@@ -19,9 +19,8 @@ Design constraints, in order:
    Algorithm-R reservoir keeps a uniform sample at fixed memory.
 
 A registry constructed with ``enabled=False`` hands out shared no-op
-instruments — `repro.serving.Engine(metrics=obs.NULL)` is the
-instrumentation-off baseline the load benchmark's overhead measurement
-compares against.
+instruments — `repro.serving.Engine(metrics=obs.NULL)` serves
+uninstrumented.
 """
 
 from __future__ import annotations

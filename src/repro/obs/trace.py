@@ -1,6 +1,6 @@
 """JSONL span tracing with a near-free disabled path.
 
-A trace is a flat JSONL file, one object per line, written as spans
+A trace is a flat JSONL file, one object per line, in the order spans
 *close* (children therefore appear before their parents, like Chrome's
 trace events). Schema:
 
@@ -15,13 +15,20 @@ stack, so spans nest correctly across threads and asyncio tasks alike.
 The sink is the path in ``$REPRO_TRACE`` (read once, lazily) or whatever
 `configure_trace(path)` set last; `configure_trace(None)` turns tracing
 off. With no sink, `span()` yields immediately and `event()` returns —
-one predicate check per call — which is what keeps the serving engine's
-instrumentation overhead under 2% with tracing off (the load benchmark
-measures it; see docs/observability.md).
+one predicate check per call, no JAX import.
+
+With a sink, records are kept in memory and written when the sink is
+closed (`configure_trace` with ``None`` or another path, or interpreter
+exit): a span costs a dict and a list append, not a file write inside
+its parent's duration. Every span also enters a
+`jax.profiler.TraceAnnotation` of its name (the attributes stay in the
+JSONL record), so a profile taken meanwhile holds the span on its host
+plane, on the same clock as the device's operations.
 """
 
 from __future__ import annotations
 
+import atexit
 import contextlib
 import contextvars
 import itertools
@@ -34,6 +41,8 @@ _ENV_VAR = "REPRO_TRACE"
 
 _sink = None                  # open file object, or None
 _sink_path: str | None = None
+_records: list[dict] = []     # closed spans and events not yet written
+_annotation = None            # jax.profiler.TraceAnnotation, once tracing
 _env_checked = False
 _write_lock = threading.Lock()
 _ids = itertools.count(1)
@@ -56,25 +65,36 @@ def _ensure_env() -> None:
 
 def configure_trace(path: str | os.PathLike | None) -> None:
     """Point the trace sink at ``path`` (append mode; parent dirs are
-    created), or disable tracing with ``None``. Replaces any previous
-    sink. Takes precedence over ``$REPRO_TRACE``."""
-    global _sink, _sink_path, _env_checked
+    created), or disable tracing with ``None``. Writes every record the
+    previous sink holds, then replaces it. Takes precedence over
+    ``$REPRO_TRACE``."""
+    global _sink, _sink_path, _env_checked, _annotation, _records
     _env_checked = True          # explicit config wins over the env var
-    if _sink is not None:
-        try:
-            _sink.close()
-        except OSError:
-            pass
-        _sink = None
-        _sink_path = None
-    if path is None:
-        return
-    p = os.fspath(path)
-    d = os.path.dirname(p)
-    if d:
-        os.makedirs(d, exist_ok=True)
-    _sink = open(p, "a")
-    _sink_path = p
+    with _write_lock:
+        if _sink is not None:
+            records, _records = _records, []
+            try:
+                _sink.writelines(json.dumps(r, default=str) + "\n"
+                                 for r in records)
+                _sink.close()
+            except OSError:
+                pass
+            _sink = None
+            _sink_path = None
+        if path is None:
+            return
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        p = os.fspath(path)
+        d = os.path.dirname(p)
+        if d:
+            os.makedirs(d, exist_ok=True)
+        _sink = open(p, "a")
+        _sink_path = p
+
+
+atexit.register(configure_trace, None)
 
 
 def trace_active() -> bool:
@@ -90,19 +110,15 @@ def trace_path() -> str | None:
     return _sink_path
 
 
-def _write(obj: dict) -> None:
-    line = json.dumps(obj, default=str)
+def _record(obj: dict) -> None:
     with _write_lock:
-        sink = _sink
-        if sink is None:          # configure_trace(None) raced us
-            return
-        sink.write(line + "\n")
-        sink.flush()              # crash-visible; tracing is opt-in
+        if _sink is not None:    # configure_trace(None) may have raced us
+            _records.append(obj)
 
 
 @contextlib.contextmanager
 def span(name: str, **attrs):
-    """Time a block and emit one JSONL span on exit.
+    """Time a block and record one JSONL span on exit.
 
     Yields the span id (None when tracing is off — callers never
     branch on it). Attributes must be JSON-serializable; anything else
@@ -119,23 +135,24 @@ def span(name: str, **attrs):
     ts = time.time()
     t0 = time.perf_counter()
     try:
-        yield sid
+        with _annotation(name):
+            yield sid
     except BaseException as e:
         attrs = {**attrs, "error": type(e).__name__}
         raise
     finally:
         _stack.reset(token)
-        _write({"type": "span", "name": name, "id": sid,
-                "parent": parent, "ts": ts,
-                "dur_s": time.perf_counter() - t0, **attrs})
+        _record({"type": "span", "name": name, "id": sid,
+                 "parent": parent, "ts": ts,
+                 "dur_s": time.perf_counter() - t0, **attrs})
 
 
 def event(name: str, **attrs) -> None:
-    """Emit one instantaneous JSONL event (parented to the enclosing
+    """Record one instantaneous JSONL event (parented to the enclosing
     span, when inside one). No-op with tracing off."""
     if not trace_active():
         return
     stack = _stack.get()
-    _write({"type": "event", "name": name, "id": next(_ids),
-            "parent": stack[-1] if stack else None,
-            "ts": time.time(), **attrs})
+    _record({"type": "event", "name": name, "id": next(_ids),
+             "parent": stack[-1] if stack else None,
+             "ts": time.time(), **attrs})

@@ -106,10 +106,8 @@ class Engine:
         self._m_completed = m.counter("engine.requests_completed")
         self._m_rejected = m.counter("engine.rejections")
         self._m_refills = m.counter("engine.refills_total")
-        self._m_tps = m.gauge("engine.tokens_per_sec")
+        self._m_d2h = m.counter("engine.d2h_bytes")
         self._m_queue = m.gauge("engine.queue_depth")
-        self._m_slot_pos = [m.gauge(f"engine.slot_pos.{s}")
-                            for s in range(slots)]
         #: True when the last `run_until_drained` hit ``max_steps`` with
         #: requests still active (only reachable with on_truncate="warn").
         self.truncated = False
@@ -133,19 +131,27 @@ class Engine:
         # state from its previous occupant must still be cleared).
         self._blank_slot = api.make_decode_cache(cfg, 1, max_seq,
                                                  dtype=jnp.float32)
-        self._decode = jax.jit(
-            lambda p, c, t, pos: api.decode_step(p, cfg, c, t, pos))
+        # Named functions, so that a device trace shows the modules as
+        # jit_engine_decode, jit_engine_decode_hidden, jit_engine_prefill.
+        def engine_decode(p, c, t, pos):
+            return api.decode_step(p, cfg, c, t, pos)
+
         # Sparse mode stops the jit'd step at the hidden states; the
         # pooled (slots, 1, d) batch then feeds the compressed head's
         # fused SpMM kernel (one entropy decode per step, amortized
         # over every active slot).
-        self._decode_hidden = jax.jit(
-            lambda p, c, t, pos: api.decode_hidden(p, cfg, c, t, pos))
+        def engine_decode_hidden(p, c, t, pos):
+            return api.decode_hidden(p, cfg, c, t, pos)
+
         # Batched prefill: the whole prompt in one forward pass. jit
         # retraces once per distinct prompt length (real engines bucket
         # lengths; the pools this repo serves see a handful).
-        self._prefill = jax.jit(
-            lambda p, b: api.prefill(p, cfg, b, max_seq=max_seq))
+        def engine_prefill(p, b):
+            return api.prefill(p, cfg, b, max_seq=max_seq)
+
+        self._decode = jax.jit(engine_decode)
+        self._decode_hidden = jax.jit(engine_decode_hidden)
+        self._prefill = jax.jit(engine_prefill)
 
     # --- sparse head ---------------------------------------------------------
     @classmethod
@@ -240,13 +246,13 @@ class Engine:
                 self.active[s] = r
                 t0 = time.perf_counter()
                 with obs.span("engine.prefill", rid=r.rid, slot=s,
-                              prompt_len=int(len(r.prompt))):
+                              prompt_len=int(len(r.prompt)),
+                              queued_s=None if r.t_submit is None
+                              else t0 - r.t_submit):
                     self._prefill_slot(s, r)
                 self._m_prefill.observe(time.perf_counter() - t0)
                 self._m_refills.add(1)
         self._m_queue.set(len(self.queue))
-        for s, g in enumerate(self._m_slot_pos):
-            g.set(int(self.pos[s]))
 
     def _prefill_slot(self, s: int, r: Request):
         """Admit request ``r`` into slot ``s``: run ``prompt[:-1]``
@@ -271,8 +277,9 @@ class Engine:
             # 1-token prompt: nothing to prefill, but the slot's cache
             # lines still hold its previous occupant's state.
             req_cache = self._blank_slot
-        self.cache = api.cache_insert_slot(self.cfg, self.cache,
-                                           req_cache, s)
+        with obs.span("engine.insert_slot"):
+            self.cache = api.cache_insert_slot(self.cfg, self.cache,
+                                               req_cache, s)
         self.pos[s] = L - 1
 
     # --- sampling --------------------------------------------------------------
@@ -298,10 +305,11 @@ class Engine:
         vector threaded through `api.decode_step` / `decode_hidden`):
         mixed-length prompts and mid-flight refills stay token-identical
         to running each request alone. Instrumented: step wall time
-        splits into refill (admission + batched prefill) and pooled
-        decode spans; tokens/sec, slot occupancy, per-slot position
-        gauges, TTFT and end-to-end latency land in `self.metrics`
-        (see docs/observability.md for the names).
+        splits into refill (admission + batched prefill), pooled decode
+        (with the logits' copy to the host) and sampling spans; slot
+        occupancy, logits bytes copied, TTFT and end-to-end latency
+        land in `self.metrics` (see docs/observability.md for the
+        names).
         """
         t_step0 = time.perf_counter()
         with obs.span("engine.step"):
@@ -330,46 +338,19 @@ class Engine:
                     # mode.
                     hidden, self.cache = self._decode_hidden(
                         self.params, self.cache, jnp.asarray(toks), pos)
-                    logits = np.asarray(self._head(hidden),
-                                        dtype=np.float32)
+                    logits = self._head(hidden)
                 else:
                     logits, self.cache = self._decode(self.params,
                                                       self.cache,
                                                       jnp.asarray(toks),
                                                       pos)
+                nbytes = logits.nbytes     # crosses in the logits' dtype
+                self._m_d2h.add(nbytes)
+                with obs.span("engine.logits_d2h", bytes=nbytes):
                     logits = np.asarray(logits, dtype=np.float32)
             t_decode = time.perf_counter() - t_dec0
-            now = time.perf_counter()
-            produced = 0
-            for s, r in enumerate(self.active):
-                if r is None:
-                    continue
-                nxt = self._select_token(logits[s, 0])
-                r.out.append(nxt)
-                produced += 1
-                self.pos[s] += 1
-                if self.pos[s] >= self.max_seq:
-                    # Unreachable by construction: admission control
-                    # bounds prompt_len + max_new_tokens <= max_seq.
-                    raise RuntimeError(
-                        f"slot {s} position {int(self.pos[s])} overran "
-                        f"max_seq={self.max_seq} — admission control "
-                        f"failed")
-                if len(r.out) == 1:
-                    r.t_first = now
-                    if r.t_submit is not None:
-                        self._m_ttft.observe(now - r.t_submit)
-                if len(r.out) >= r.max_new_tokens:
-                    r.done = True
-                    r.t_done = now
-                    self.active[s] = None
-                    self.pos[s] = -1
-                    self.finished.append(r)
-                    self._m_completed.add(1)
-                    if r.t_submit is not None:
-                        self._m_e2e.observe(now - r.t_submit)
-            for s, g in enumerate(self._m_slot_pos):
-                g.set(int(self.pos[s]))
+            with obs.span("engine.sample"):
+                produced = self._sample(logits)
         dt = time.perf_counter() - t_step0
         self._m_step.observe(dt)
         self._m_refill.observe(t_refill)
@@ -377,7 +358,41 @@ class Engine:
         self._m_occupancy.observe(n_active / self.slots)
         self._m_tokens.add(produced)
         self._m_steps.add(1)
-        self._m_tps.set(produced / dt if dt > 0 else 0.0)
+        return produced
+
+    def _sample(self, logits: np.ndarray) -> int:
+        """Pick each active slot's next token from its row of the
+        (slots, 1, vocab) host ``logits``, advance the slot and retire
+        finished requests; returns #tokens."""
+        now = time.perf_counter()
+        produced = 0
+        for s, r in enumerate(self.active):
+            if r is None:
+                continue
+            nxt = self._select_token(logits[s, 0])
+            r.out.append(nxt)
+            produced += 1
+            self.pos[s] += 1
+            if self.pos[s] >= self.max_seq:
+                # Unreachable by construction: admission control
+                # bounds prompt_len + max_new_tokens <= max_seq.
+                raise RuntimeError(
+                    f"slot {s} position {int(self.pos[s])} overran "
+                    f"max_seq={self.max_seq} — admission control "
+                    f"failed")
+            if len(r.out) == 1:
+                r.t_first = now
+                if r.t_submit is not None:
+                    self._m_ttft.observe(now - r.t_submit)
+            if len(r.out) >= r.max_new_tokens:
+                r.done = True
+                r.t_done = now
+                self.active[s] = None
+                self.pos[s] = -1
+                self.finished.append(r)
+                self._m_completed.add(1)
+                if r.t_submit is not None:
+                    self._m_e2e.observe(now - r.t_submit)
         return produced
 
     def run_until_drained(self, max_steps: int = 10000, *,

@@ -10,7 +10,10 @@ decode over mixed-length prompts with mid-flight refills must be
 token-identical to running each request alone.
 """
 
+import json
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -183,9 +186,6 @@ class TestAdmissionControl:
         snap = eng.metrics.snapshot()
         assert snap["counters"]["engine.refills_total"] == 3
         assert snap["counters"]["engine.rejections"] == 0
-        # per-slot position gauges exist and read -1 once drained
-        for s in range(eng.slots):
-            assert snap["gauges"][f"engine.slot_pos.{s}"] == -1.0
 
 
 class TestSampling:
@@ -313,3 +313,103 @@ class TestEncdecPerSlot:
         eng.run_until_drained()
         for r in reqs:
             assert list(r.out) == want[r.rid]
+
+
+class TestSpans:
+    """With tracing on, each step records where its host time goes:
+    admission (prefill, cache insert), the pooled decode with the
+    head's upload and the logits' copy to the host, and sampling."""
+
+    PARENT = {"engine.refill": "engine.step",
+              "engine.prefill": "engine.refill",
+              "engine.insert_slot": "engine.prefill",
+              "engine.decode": "engine.step",
+              "serving.sparse_apply": "engine.decode",
+              "kernels.upload": "serving.sparse_apply",
+              "engine.logits_d2h": "engine.decode",
+              "engine.sample": "engine.step"}
+
+    @pytest.fixture(scope="class", params=["dense", "compressed"])
+    def traced(self, request, tmp_path_factory):
+        cfg, params = _params_for("smollm-135m", vocab=64, seed=5)
+        head = None
+        if request.param == "compressed":
+            head = Engine.compress_lm_head(cfg, params, sparsity=0.6,
+                                           value_bits=5, lane_width=32)
+        eng = Engine(cfg, params, slots=3, max_seq=32, sparse_head=head,
+                     metrics=obs.MetricsRegistry())
+        h2d = obs.default_registry().counter("kernels.h2d_bytes")
+        h2d_before = h2d.value
+        path = tmp_path_factory.mktemp("spans") / "trace.jsonl"
+        obs.configure_trace(path)
+        try:
+            for p in _prompts(cfg, (1, 4, 6, 3), seed=11):
+                eng.submit(p, 3)
+            eng.run_until_drained()
+        finally:
+            obs.configure_trace(None)
+        with open(path) as f:
+            spans = [r for r in map(json.loads, f) if r["type"] == "span"]
+        return eng, spans, h2d.value - h2d_before
+
+    def test_spans_nest_under_their_parents(self, traced):
+        eng, spans, _ = traced
+        by_id = {s["id"]: s for s in spans}
+        names = {s["name"] for s in spans}
+        want = set(self.PARENT) | {"engine.step"}
+        if eng.sparse_head is None:
+            want -= {"serving.sparse_apply", "kernels.upload"}
+        assert names == want
+        for s in spans:
+            if s["name"] in self.PARENT:
+                assert by_id[s["parent"]]["name"] == self.PARENT[s["name"]]
+        steps = [s for s in spans if s["name"] == "engine.step"]
+        for kind in ("engine.decode", "engine.sample",
+                     "engine.logits_d2h"):
+            assert sum(s["name"] == kind for s in spans) == len(steps)
+        prefills = [s for s in spans if s["name"] == "engine.prefill"]
+        assert len(prefills) == 4
+        assert all(s["queued_s"] >= 0 for s in prefills)
+        assert sorted(s["prompt_len"] for s in prefills) == [1, 3, 4, 6]
+
+    @pytest.mark.parametrize("traced", ["compressed"], indirect=True)
+    def test_upload_bytes_are_the_packs_host_bytes(self, traced):
+        eng, spans, h2d = traced
+        pm = eng.sparse_head.packed
+        host = sum(a.nbytes for a in (
+            pm.stream, pm.stream_base, pm.esc, pm.esc_base, pm.nsegs,
+            pm.nnz, pm.tab_symbol, pm.tab_meta))
+        up = [s["bytes"] for s in spans if s["name"] == "kernels.upload"]
+        assert up and all(b == host for b in up)
+        assert h2d == sum(up)
+
+    def test_logits_bytes_are_slots_by_vocab(self, traced):
+        eng, spans, _ = traced
+        toks = jnp.zeros((eng.slots, 1), jnp.int32)
+        pos = jnp.asarray(eng.pos)
+        if eng.sparse_head is None:
+            logits = jax.eval_shape(eng._decode, eng.params, eng.cache,
+                                    toks, pos)[0]
+        else:
+            hidden = jax.eval_shape(eng._decode_hidden, eng.params,
+                                    eng.cache, toks, pos)[0]
+            logits = jax.eval_shape(eng._head, hidden)
+        want = eng.slots * eng.cfg.vocab * logits.dtype.itemsize
+        d2h = [s["bytes"] for s in spans if s["name"] == "engine.logits_d2h"]
+        assert d2h and all(b == want for b in d2h)
+        assert eng.metrics.counter("engine.d2h_bytes").value == sum(d2h)
+
+    def test_jitted_steps_have_stable_names(self):
+        """A device trace names each module after its function, so the
+        engine's jits are named functions, not lambdas."""
+        cfg, params = _params_for("smollm-135m", vocab=64, seed=5)
+        eng = Engine(cfg, params, slots=2, max_seq=16,
+                     metrics=obs.MetricsRegistry())
+        step = (eng.params, eng.cache, jnp.zeros((2, 1), jnp.int32),
+                jnp.asarray(eng.pos))
+        prompt = {"inputs": jnp.zeros((1, 3), jnp.int32)}
+        for fn, args, name in (
+                (eng._decode, step, "jit_engine_decode"),
+                (eng._decode_hidden, step, "jit_engine_decode_hidden"),
+                (eng._prefill, (eng.params, prompt), "jit_engine_prefill")):
+            assert f"module @{name} " in fn.lower(*args).as_text()

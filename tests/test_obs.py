@@ -156,6 +156,75 @@ class TestTrace:
             assert sid is None
         obs.event("off")      # must not raise
 
+    def test_sink_is_written_only_when_closed(self, tmp_path):
+        p = tmp_path / "trace.jsonl"
+        obs.configure_trace(p)
+        try:
+            for i in range(50):
+                with obs.span("outer", i=i):
+                    with obs.span("inner"):
+                        obs.event("mark")
+            with pytest.raises(ValueError):
+                with obs.span("crash"):
+                    raise ValueError
+            assert p.stat().st_size == 0      # nothing on the hot path
+        finally:
+            obs.configure_trace(None)
+        recs = [json.loads(line) for line in p.read_text().splitlines()]
+        names = [r["name"] for r in recs]
+        assert names == ["mark", "inner", "outer"] * 50 + ["crash"]
+        assert [r["i"] for r in recs if r["name"] == "outer"] \
+            == list(range(50))
+        assert recs[-1]["error"] == "ValueError"
+
+    def test_spans_enter_a_profiler_annotation_only_when_tracing(
+            self, tmp_path, monkeypatch):
+        from repro.obs import trace as trace_mod
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                return False
+        monkeypatch.setattr(trace_mod, "_annotation", Annotation)
+        obs.configure_trace(None)
+        with obs.span("off"):
+            pass
+        assert entered == []
+        obs.configure_trace(tmp_path / "t.jsonl")
+        try:
+            with obs.span("outer", k=1):
+                with obs.span("inner"):
+                    pass
+        finally:
+            obs.configure_trace(None)
+        assert entered == ["outer", "inner"]
+
+    def test_tracing_off_needs_no_jax(self, tmp_path, monkeypatch):
+        """The module imports, and spans run, with JAX unimportable; only
+        turning tracing on imports it."""
+        import importlib.util
+        import sys
+
+        from repro.obs import trace as trace_mod
+        monkeypatch.setitem(sys.modules, "jax", None)
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        spec = importlib.util.spec_from_file_location(
+            "repro_obs_trace_without_jax", trace_mod.__file__)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        mod.configure_trace(None)
+        with mod.span("off") as sid:
+            assert sid is None
+        with pytest.raises(ImportError):
+            mod.configure_trace(tmp_path / "t.jsonl")
+        assert mod.trace_path() is None
+
 
 class TestEngineMetrics:
     @pytest.fixture(scope="class")
