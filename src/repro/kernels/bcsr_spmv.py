@@ -7,8 +7,9 @@ block column -1 and zero values), every row of the block row holds the
 block's column and its ``c`` values — ``(W, Mp)`` block columns and
 ``(W, c, Mp)`` values.  One program per 128 rows walks the block slots,
 expands each block column into its ``c`` absolute columns, gathers ``x``
-(one-hot on the MXU) and contracts the dense r x c tiles — no per-element
-index storage, which is the format's whole bargain: the cost model
+(`repro.kernels.common.gather_mul`) and contracts the dense r x c tiles
+— no per-element index storage, which is the format's whole bargain: the
+cost model
 charges BCSR plain lock-step work over the *filled* cells
 (`Fingerprint.block_fill_elems`), with no row-sequential penalty and no
 decode term.

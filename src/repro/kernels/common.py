@@ -20,10 +20,10 @@ Everything here lowers to Mosaic (TPU Pallas):
   pair.  Digits are accumulated in groups whose radix product stays
   below 2^32 ("accumulate returned digits into a digit/base pair",
   paper Section IV-F) and folded into the limbs once per group.
-* Gathers (stream claims, table lookups, escape claims) are in-tile
-  ``take_along_axis`` over each (8, 128) block, selected across blocks
-  (`lookup`).  ``value * x[col]`` is ``x`` times a one-hot matrix that
-  carries the value, on the MXU at ``HIGHEST`` precision (`gather_mul`).
+* Gathers are in-tile ``take_along_axis`` over each (8, 128) block,
+  selected across blocks: stream claims, table lookups and escape claims
+  (`lookup`), and ``x[col]`` for every batch row of ``x^T`` at once
+  (`gather_mul`), then one multiply by the value.
 * Claim ranks (the warp ballot + popc of the paper) are a triangular
   ones matmul over the lanes, segmented per slice; counts are <= 128,
   so f32 holds them exactly.
@@ -42,12 +42,9 @@ from repro.core.params import DtansParams
 #: Row width of every stream / table block (the TPU lane count).
 LANES = 128
 
-#: Rows of the one-hot block `gather_mul` builds per MXU product.
-X_CHUNK = 2048
-
-#: Largest ``x`` length the compiled one-hot gather serves: beyond it the
-#: statically unrolled chunks stop paying (`repro.kernels.ops` raises).
-MAX_X_ROWS = 8 * X_CHUNK
+#: Largest ``x`` length the compiled kernels serve: `gather_mul` unrolls
+#: one select per 128 columns, and `repro.kernels.ops` raises beyond it.
+MAX_X_ROWS = 16384
 
 # ``tab_meta`` bit layout: digit | base << 15 | is_escape << 31.
 META_DIGIT_MASK = 0x7FFF
@@ -139,29 +136,30 @@ def lookup(table, idx):
 
 def gather_mul(xt, col, val):
     """``val * xt[:, col]`` for ``xt: (B, n)`` and ``(1, N)`` columns and
-    values (a column outside ``[0, n)`` contributes 0): ``xt`` times a
-    one-hot matrix carrying ``val``, on the MXU in ``X_CHUNK``-row pieces.
+    values; a column outside ``[0, n)`` contributes 0.
 
-    Each output is one product plus exact zeros (for finite ``x``), so it
-    is rounded once, whatever the caller adds it to.  A separate multiply
-    would not be: XLA:CPU fuses a multiply and the caller's add into an
-    FMA in some fusions and not in others, which breaks the bit-identity
-    of the schedules in interpret mode.  The final select is a no-op
-    that keeps XLA:CPU from folding the caller's add into a
-    matrix-vector product (its output fusion) for the same reason."""
-    n = xt.shape[1]
-    zero = jnp.zeros((), xt.dtype)
-    val = val.astype(xt.dtype)
-    out = None
-    for c0 in range(0, n, X_CHUNK):
-        cn = min(X_CHUNK, n - c0)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (cn, col.shape[1]), 0)
-        oh = jnp.where(rows + c0 == col, val, zero)
-        part = jnp.dot(xt[:, c0:c0 + cn] if cn < n else xt, oh,
-                       precision=jax.lax.Precision.HIGHEST,
-                       preferred_element_type=xt.dtype)
-        out = part if out is None else out + part
-    return jnp.where(col >= 0, out, zero)
+    Per 128-column block of ``xt``, an in-tile lane gather of
+    ``col & 127`` for all ``B`` rows (one per 8-row tile on the chip),
+    kept where ``col >> 7`` names the block (as `lookup` does), then one
+    multiply by ``val``.  The compiled kernels get ``B`` a multiple of 8
+    and ``n`` of 128 (`repro.kernels.tiling`); interpreted, the last
+    block may be short.
+
+    Each output is one product, rounded once, whatever the caller adds
+    it to.  The final select gives padding lanes an exact 0 whatever
+    ``x`` holds, and stands between the multiply and the caller's add:
+    XLA:CPU fuses the two into an FMA in some fusions and not in others,
+    which would break the bit-identity of the schedules in interpret
+    mode."""
+    B, n = xt.shape
+    blk = col >> 7
+    lane = jnp.broadcast_to(col & (LANES - 1), (B, col.shape[1]))
+    xg = None
+    for c0 in range(0, n, LANES):
+        g = jnp.take_along_axis(xt[:, c0:c0 + LANES], lane, axis=1)
+        xg = g if xg is None else jnp.where(blk == c0 // LANES, g, xg)
+    prod = xg * val.astype(xt.dtype)
+    return jnp.where((col >= 0) & (col < n), prod, jnp.zeros((), xt.dtype))
 
 
 def _ranks(take, arr: DecodeArrays):

@@ -15,11 +15,13 @@ program, the kernel holds in VMEM:
 The decode loop is `lax.fori_loop` over the matrix-wide max segment count;
 lanes past their row's end are masked (same lock-step schedule as
 `repro.core.dtans_vec.decode_lanes`).  Stream claims and table lookups are
-in-tile gathers (`repro.kernels.common.lookup`); each ``value * x[col]``
-is a value-carrying one-hot product on the MXU
-(`repro.kernels.common.gather_mul`), and the products are summed in the
-same order on every path, so all schedules below are bitwise identical to
-the plain kernel.
+in-tile gathers (`repro.kernels.common.lookup`), and so is ``x[col]``:
+one lane gather per 128-column block of the ``x^T`` tile, for all ``Bt``
+rows at once, then one f32 multiply by the value
+(`repro.kernels.common.gather_mul`).  The ``x^T`` tile is padded to whole
+(8, 128) blocks (`repro.kernels.tiling`).  Each product is rounded once
+and the products are summed in the same order on every path, so all
+schedules below are bitwise identical to the plain kernel.
 
 Three static knobs (docs/kernels.md has the full contract):
 

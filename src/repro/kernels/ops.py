@@ -126,8 +126,8 @@ def check_compiled(kind: str, dtype, n: int, lanes: int = LANES) -> None:
                          f"the TPU kernel needs {LANES} (a lane width "
                          f"that divides {LANES})")
     if n > MAX_X_ROWS:
-        raise ValueError(f"{kind}: x has {n} rows; the compiled one-hot x "
-                         f"gather serves at most {MAX_X_ROWS}")
+        raise ValueError(f"{kind}: x has {n} rows; the compiled x gather "
+                         f"serves at most {MAX_X_ROWS}")
 
 
 def get_packed(mat: CSRdtANS) -> PackedMatrix:

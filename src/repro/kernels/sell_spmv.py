@@ -3,12 +3,12 @@
 The pack stores the padded ELL layout transposed, rows on lanes:
 ``(Wg, Mp)`` column indices and values, ``Wg`` the matrix-wide max row
 nnz and ``Mp`` the row count rounded up to 128.  One program per 128
-rows walks the ``Wg`` positions, gathers ``x`` per position (one-hot on
-the MXU, `repro.kernels.common.gather_mul`) and accumulates in position
-order.  This is the "fastest cuSPARSE format" stand-in the benchmark
-harness compares with the fused dtANS kernel under the same roofline
-model (both kernels are memory-bound; the ratio of bytes moved predicts
-the speedup, Section V-B of the paper).
+rows walks the ``Wg`` positions, gathers ``x`` per position (in-tile
+lane gathers, `repro.kernels.common.gather_mul`) and accumulates in
+position order.  This is the "fastest cuSPARSE format" stand-in the
+benchmark harness compares with the fused dtANS kernel under the same
+roofline model (both kernels are memory-bound; the ratio of bytes moved
+predicts the speedup, Section V-B of the paper).
 """
 
 from __future__ import annotations

@@ -31,7 +31,8 @@ column sees exactly the per-column arithmetic of the untiled kernel
 (same decode, same gather, same accumulation order), so blocked
 results are REQUIRED to be bitwise equal to the unblocked kernels at
 every ``bn`` — the conformance suite pins both schedules with exact
-``==``.  Ragged tails zero-pad x to ``J*bn`` columns and slice back.
+``==``.  ``x^T`` is zero-padded to whole (8, 128) blocks, and ragged
+tails to ``J*bn`` columns; the output is sliced back.
 
 The pure sizing helpers (`choose_bn` / `n_col_tiles`) are numpy-free
 and jax-free so `repro.autotune.cost_model` can price tiling without
@@ -131,14 +132,14 @@ def _blocked_spmm(kernel, mat_args, mat_specs, x, *, lanes, grid_s, bn,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    from repro.kernels.common import LANES, X_CHUNK
+    from repro.kernels.common import LANES
 
     n, B = x.shape
     S = int(grid_s)
-    xt = x.T                                              # (B, n)
-    if n > X_CHUNK and n % LANES:   # gather_mul slices x in 128-aligned chunks
-        xt = jnp.pad(xt, ((0, 0), (0, LANES - n % LANES)))
-        n = xt.shape[1]
+    # `gather_mul` gathers whole (8, 128) blocks of x^T: zero rows and
+    # columns pad it to them, and the extra output rows are sliced off.
+    xt = jnp.pad(x.T, ((0, -B % 8), (0, -n % LANES)))     # (Bp, np)
+    n = xt.shape[1]
 
     def call(xt, bt, J):
         if J == 1:
@@ -163,11 +164,10 @@ def _blocked_spmm(kernel, mat_args, mat_specs, x, *, lanes, grid_s, bn,
 
     bn = None if bn is None else -(-int(bn) // 8) * 8
     if bn is None or bn >= B:
-        y = call(xt, B, 1)
+        y = call(xt, xt.shape[0], 1)
     else:
         J = -(-B // bn)
-        if B % bn:
-            xt = jnp.pad(xt, ((0, J * bn - B), (0, 0)))
+        xt = jnp.pad(xt, ((0, J * bn - xt.shape[0]), (0, 0)))
         if resolve_tile_mode(tile_mode, interpret) == "loop":
             ys = jax.lax.map(lambda xj: call(xj, bn, 1),
                              xt.reshape(J, bn, n))        # (J, S, bn, N)

@@ -127,3 +127,26 @@ class TestSellKernel:
         np.testing.assert_allclose(y_k, y_r, rtol=rtol)
         np.testing.assert_allclose(y_k, a.to_dense() @ x, rtol=rtol,
                                    atol=1e-5 if dtype == np.float32 else 0)
+
+
+@pytest.mark.parametrize("n", [576, 4096])
+@pytest.mark.parametrize("bt", [1, 4, 16, 32])
+def test_gather_mul_is_one_f32_product(bt, n):
+    """`gather_mul` gives ``val * xt[:, col]`` bit for bit, 0 on padding
+    lanes (column -1), at block edges and on a short last block."""
+    import jax.numpy as jnp
+
+    from repro.kernels.common import LANES, gather_mul
+
+    rng = _rng(bt * n)
+    xt = rng.standard_normal((bt, n)).astype(np.float32)
+    edges = [0, n - 1, 127, 128, 255, 256, n - 128, n - 129, -1, -1]
+    col = np.r_[edges, rng.integers(0, n, LANES - len(edges))]
+    col = col.astype(np.int32)[None, :]
+    val = rng.standard_normal((1, LANES)).astype(np.float32)
+    got = np.asarray(gather_mul(jnp.asarray(xt), jnp.asarray(col),
+                                jnp.asarray(val)))
+    want = np.where(col >= 0, val * xt[:, np.clip(col[0], 0, n - 1)],
+                    np.float32(0))
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))

@@ -5,7 +5,8 @@ Interpret mode (every other kernel test) cannot see what the chip's
 compiler refuses: blocks off the (8, 128) tiling, 64-bit operands,
 gathers and scans Mosaic does not lower.  These tests compile each kernel
 at the shapes it serves — the smollm-135m LM head (49152 x 576, sparsity
-0.8, lane width 128) and an MLP down projection (576 x 1536) — and check
+0.8, lane width 128), the yi9b-batch cell's head (8000 x 4096) and an MLP
+down projection (576 x 1536) — and check
 that the program holds a ``tpu_custom_call`` whose operands and results
 are all 32-bit.  Nothing runs; a compile that passes is not a chip run.
 
@@ -31,10 +32,14 @@ from repro.core.params import PAPER
 #: one shared 4096-slot table (32 rows), at most 40 segments per row.
 HEAD_M, HEAD_N = 49152, 576
 GROUPS, STREAM_ROWS, ESC_ROWS, TABLE_ROWS, MAX_NSEG = 384, 56, 8, 32, 40
+#: Per head: x rows, programs, stream rows, segments per row.  The
+#: yi9b-batch cell's head (8000 x 4096, the same pack settings) has 63
+#: programs of a 352-row stream and at most 227 segments per row.
+HEADS = {"smollm": (HEAD_N, GROUPS, STREAM_ROWS, MAX_NSEG),
+         "yi": (4096, 63, 352, 227)}
 #: Uncoded packs of the same head: max row nnz, BCSR 4x4 block slots.
 ELL_WIDTH, BLOCK_SLOTS, BLOCK = 160, 144, 4
-#: An MLP down projection (d_model x d_ff): the longest x the engine
-#: gathers.
+#: smollm-135m's MLP down projection (d_model x d_ff).
 MLP_M, MLP_N = 576, 1536
 
 _WIDE = re.compile(r"\b[usf]64\[")
@@ -109,16 +114,21 @@ _STATICS = dict(params=PAPER, pattern=(0,) * PAPER.l, max_nseg=MAX_NSEG,
                 lane_width=128, interpret=False)
 
 
-@pytest.mark.parametrize("variant,B", [
-    ("plain", 1), ("plain", 4), ("pipeline", 4), ("shared_cols", 4),
-    ("grid_tiles", 32)])
-def test_dtans_head_compiles(spec, variant, B):
+@pytest.mark.parametrize("head,variant,B", [
+    pytest.param("smollm", v, b, id=f"{v}-{b}") for v, b in [
+        ("plain", 1), ("plain", 4), ("pipeline", 4), ("shared_cols", 4),
+        ("grid_tiles", 32)]] + [
+    pytest.param("yi", v, 16, id=f"yi-{v}-16") for v in ["plain",
+                                                         "pipeline"]])
+def test_dtans_head_compiles(spec, head, variant, B):
     from repro.kernels.dtans_spmv import dtans_spmm_pallas
     kw = {"plain": {}, "pipeline": {"pipeline": True},
           "shared_cols": {"shared_cols": True},
           "grid_tiles": {"bn": 8, "tile_mode": "grid"}}[variant]
-    _compile(lambda m, x: dtans_spmm_pallas(m, x, **_STATICS, **kw),
-             _dtans_mats(spec), spec((HEAD_N, B), jnp.float32))
+    n, groups, stream_rows, max_nseg = HEADS[head]
+    statics = {**_STATICS, "max_nseg": max_nseg, **kw}
+    _compile(lambda m, x: dtans_spmm_pallas(m, x, **statics),
+             _dtans_mats(spec, groups, stream_rows), spec((n, B), jnp.float32))
 
 
 def test_dtans_spmv_head_compiles(spec):
@@ -128,8 +138,7 @@ def test_dtans_spmv_head_compiles(spec):
 
 
 def test_dtans_mlp_projection_compiles(spec):
-    """x of d_ff = 1536 rows, the longest x the engine's projections
-    gather."""
+    """x of d_ff = 1536 rows, smollm-135m's widest projection input."""
     from repro.kernels.dtans_spmv import dtans_spmm_pallas
     _compile(lambda m, x: dtans_spmm_pallas(m, x, **_STATICS),
              _dtans_mats(spec, groups=MLP_M // 128, stream_rows=144),
