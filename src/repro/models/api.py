@@ -103,7 +103,13 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
     with hidden (B, 1, d_model) — what a compressed LM head
     (`repro.serving.sparse_linear.SparseLinear`) consumes in place of
     `decode_step`'s dense-logits path. ``decode_step(...) ==
-    (lm_head(params["embed"], hidden), cache)`` for every family."""
+    (lm_head(params["embed"], hidden), cache)`` for every family.
+
+    The returned cache is ``caches`` with this step's state of each
+    active slot written in: one K/V row per attention layer (and the
+    new SSM state where the family has one). The serving engine
+    donates ``caches`` to its jit, so the write is in place: a caller
+    that donates must not reuse the cache it passed in."""
     return _mod(cfg).decode_hidden(params, cfg, caches, token, pos)
 
 
@@ -119,7 +125,10 @@ def cache_insert_slot(cfg: ArchConfig, pool, req, slot: int):
     the pooled decode cache ``pool``. Every cache line of the slot is
     overwritten — the serving engine uses this to admit a freshly
     prefilled request into a slot whose previous occupant finished,
-    without leaking the old request's KV/SSM state."""
+    without leaking the old request's KV/SSM state. The engine runs
+    it in a jit (``jit_engine_insert_slot``) that donates ``pool``, so
+    only the slot's lines are written; a caller that donates must not
+    reuse the pool it passed in."""
     return _mod(cfg).cache_insert_slot(cfg, pool, req, slot)
 
 

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from repro.models.config import ArchConfig
 from repro.models.layers import (attention, attention_init, embed,
                                  embedding_init, lm_head, mlp, mlp_init,
-                                 pos_vector, rmsnorm, rmsnorm_init)
+                                 pos_vector, rmsnorm, rmsnorm_init,
+                                 write_rows)
 from repro.models.sharding import shard
 
 DEC_PREFILL_LEN = 1024
@@ -139,7 +140,8 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
     LM head (dense or sparse) consumes; `decode_step` == lm_head of
     this (same contract as `transformer.decode_hidden`). ``pos`` may be
     () or (B,) per-slot positions (-1 = inactive slot, KV write
-    masked)."""
+    masked). The step's K/V rows go into the cache after the scan, in
+    one write (`write_rows`)."""
     x = embed(params["embed"], token)
     B = token.shape[0]
     pos = pos_vector(pos, B)          # (B,); -1 marks an inactive slot
@@ -148,13 +150,13 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
 
     def body(x, inp):
         lp, cache = inp
-        x, new_cache = _dec_layer(cfg, lp, x, positions, memory,
-                                  kv_cache=cache, cache_pos=pos)
-        return x, new_cache
+        x, rows = _dec_layer(cfg, lp, x, positions, memory,
+                             kv_cache=cache, cache_pos=pos)
+        return x, rows
 
-    x, new_kv = jax.lax.scan(body, x, (params["dec_layers"], caches["kv"]))
+    x, rows = jax.lax.scan(body, x, (params["dec_layers"], caches["kv"]))
     x = rmsnorm(params["dec_norm"], x, cfg.norm_eps)
-    return x, {"kv": new_kv, "memory": memory}
+    return x, {"kv": write_rows(caches["kv"], rows, pos), "memory": memory}
 
 
 def decode_step(params, cfg: ArchConfig, caches, token, pos):

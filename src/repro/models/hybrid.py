@@ -19,7 +19,7 @@ from repro.models.config import ArchConfig
 from repro.models.layers import (attention, attention_init, embed,
                                  embedding_init, lm_head, matmul, mlp,
                                  mlp_init, pos_vector, rmsnorm,
-                                 rmsnorm_init, _dense_init)
+                                 rmsnorm_init, write_rows, _dense_init)
 from repro.models.sharding import shard
 from repro.models.ssm import ssm_block, ssm_cache_init, ssm_init
 
@@ -194,7 +194,9 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
     this (same contract as `transformer.decode_hidden`). ``pos`` may be
     a () scalar (all slots in lock step) or a (B,) vector of per-slot
     positions; entries of -1 mark inactive slots, whose SSM state, conv
-    tail and attention KV lines all pass through unmodified."""
+    tail and attention KV lines all pass through unmodified. The shared
+    block's K/V rows go into the attention cache after the scan, in one
+    write (`write_rows`)."""
     every, n_groups, n_tail = _plan(cfg)
     x = embed(params["embed"], token)
     B = token.shape[0]
@@ -213,9 +215,9 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
     def group(x, inp):
         gp, gcache, kv = inp
         x, new_gcache = jax.lax.scan(inner, x, (gp, gcache))
-        x, new_kv = _shared_block(cfg, shared, x, x0, positions,
-                                  kv_cache=kv, cache_pos=pos)
-        return x, (new_gcache, new_kv)
+        x, rows = _shared_block(cfg, shared, x, x0, positions,
+                                kv_cache=kv, cache_pos=pos)
+        return x, (new_gcache, rows)
 
     new_attn = caches.get("attn")
     if n_groups:
@@ -223,11 +225,11 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
         gcaches = jax.tree.map(
             lambda a: a[:n_groups * every].reshape(
                 (n_groups, every) + a.shape[1:]), ssm_caches)
-        x, (ng, nkv) = jax.lax.scan(group, x, (gstack, gcaches,
-                                               caches["attn"]))
+        x, (ng, rows) = jax.lax.scan(group, x, (gstack, gcaches,
+                                                caches["attn"]))
         new_head = jax.tree.map(
             lambda a: a.reshape((n_groups * every,) + a.shape[2:]), ng)
-        new_attn = nkv
+        new_attn = write_rows(caches["attn"], rows, pos)
     if n_tail:
         tail_p = _slice_layers(params["layers"], n_groups * every,
                                cfg.n_layers)
@@ -242,7 +244,8 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
     # Inactive-slot write mask: the single-token SSM recurrence advances
     # state and conv tail for every batch row unconditionally — a pooled
     # step must not corrupt the state of slots that are not decoding
-    # (attention KV already masks its own write inside `attention`).
+    # (`write_rows` already leaves the attention lines of inactive
+    # slots as they are).
     active = pos >= 0
     new_ssm = jax.tree.map(
         lambda new, old: jnp.where(

@@ -105,12 +105,16 @@ def attention(p, cfg: ArchConfig, x, positions, *, causal=True,
     x: (B, S, d). kv: optional cross-attention memory (B, Sk, d).
     kv_cache: optional dict {k, v: (B, Smax, Hk, hd)}; cache_pos: () int
     or (B,) int32 — write position for the current step; returns
-    (out, new_cache). A (B,) cache_pos serves batch slots holding
-    requests of unequal length: slot b writes its K/V row at
-    ``cache_pos[b]`` and attends keys ``<= cache_pos[b]`` only, and a
-    *negative* position marks an inactive slot — it matches no cache
-    row, so the write is masked out entirely (the slot's live cache
-    lines survive pooled steps it does not participate in).
+    (out, new_cache). A () cache_pos returns the whole updated cache.
+    A (B,) cache_pos serves batch slots holding requests of unequal
+    length: slot b's K/V row belongs at ``cache_pos[b]``, and the slot
+    attends keys ``<= cache_pos[b]`` only, its new row included. The
+    cache is only read: new_cache is just the rows, {k, v: (B, 1, Hk,
+    hd)} in the cache's dtype, which the caller writes into its stacked
+    cache once per step (`write_rows`). A *negative* position marks an
+    inactive slot: it matches no cache row, and `write_rows` leaves its
+    lines as they are, so the slot's live cache survives pooled steps
+    it does not take part in.
     return_cache=True (prefill): return this call's {k, v} as the cache.
     """
     H = n_heads or cfg.n_heads
@@ -145,32 +149,39 @@ def attention(p, cfg: ArchConfig, x, positions, *, causal=True,
                 kv_cache["k"].dtype), idx)
             cv = jax.lax.dynamic_update_slice(kv_cache["v"], v.astype(
                 kv_cache["v"].dtype), idx)
+            new_cache = {"k": ck, "v": cv}
         else:
-            # Per-slot scatter (S == 1 decode): slot b writes at its own
-            # position cp[b]; a negative cp[b] (inactive slot) matches no
-            # cache row — the write is fully masked and the slot's cache
-            # lines pass through untouched.
+            # Per-slot decode (S == 1): slot b's new row sits at its own
+            # position cp[b] for this step's attention. The select only
+            # feeds the dots below; the cache itself is not rewritten,
+            # and only the rows go back to the caller. A negative cp[b]
+            # (inactive slot) matches no cache row.
             Smax = kv_cache["k"].shape[1]
             hit = (jnp.arange(Smax, dtype=jnp.int32)[None, :]
                    == cp[:, None])[:, :, None, None]
-            ck = jnp.where(hit, k.astype(kv_cache["k"].dtype),
-                           kv_cache["k"])
-            cv = jnp.where(hit, v.astype(kv_cache["v"].dtype),
-                           kv_cache["v"])
-        new_cache = {"k": ck, "v": cv}
+            new_cache = {"k": k.astype(kv_cache["k"].dtype),
+                         "v": v.astype(kv_cache["v"].dtype)}
+            ck = jnp.where(hit, new_cache["k"], kv_cache["k"])
+            cv = jnp.where(hit, new_cache["v"], kv_cache["v"])
         k, v = ck, cv
 
     n_rep = H // Hk
     Sk = k.shape[1]
 
     if kv_cache is not None:
-        # decode: grouped-GQA attention straight against the bf16 cache —
-        # no head-replicated K/V materialization (16x for 128q/8kv heads),
-        # no fp32 cache copy (dots accumulate in fp32 via
-        # preferred_element_type)
+        # decode: grouped-GQA attention straight against the cache, in
+        # the cache's dtype (the engine keeps it in f32) — no
+        # head-replicated K/V materialization (16x for 128q/8kv heads);
+        # the dots accumulate in fp32 via preferred_element_type.
+        # Per-slot dots run at HIGHEST: at DEFAULT precision the TPU
+        # rounds an f32 operand to bf16, and since the layer scan only
+        # reads the stacked cache, XLA hoists that conversion of the
+        # WHOLE cache out of the scan (a full read and a half-size copy
+        # every step). At HIGHEST each layer reads its slice as it is.
+        prec = None if cp.ndim == 0 else jax.lax.Precision.HIGHEST
         scale = float(1.0 / np.sqrt(hd))
         qg = q.reshape(B, S, Hk, n_rep, hd)
-        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k,
+        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k, precision=prec,
                             preferred_element_type=jnp.float32) * scale
         kpos_ids = jnp.arange(Sk, dtype=jnp.int32)
         if cp.ndim == 0:
@@ -182,7 +193,7 @@ def attention(p, cfg: ArchConfig, x, positions, *, causal=True,
         logits = jnp.where(mask, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(x.dtype), v,
-                         preferred_element_type=jnp.float32)
+                         precision=prec, preferred_element_type=jnp.float32)
         out = out.astype(x.dtype).reshape(B, S, H, hd)
     elif S > _FLASH_THRESHOLD:
         # long-sequence prefill/training: blocked online-softmax attention
@@ -207,6 +218,30 @@ def attention(p, cfg: ArchConfig, x, positions, *, causal=True,
     out = out.reshape(B, S, H * hd)
     out = matmul(out, p["wo"])
     return shard(out, "batch", "seq", "d_model"), new_cache
+
+
+def write_rows(cache, rows, pos):
+    """Write one decode step's K/V rows into a layer-stacked cache.
+
+    cache: {k, v: (L, B, Smax, Hk, hd)}; rows: {k, v: (L, B, 1, Hk, hd)},
+    what the per-slot `attention` returns, stacked by a scan over the
+    L layers; pos: (B,) int32. Slot b's rows of every layer land at
+    (:, b, pos[b]) by one dynamic-update-slice per slot, and nothing
+    else of the cache is touched: under a jit that donates ``cache``
+    each write is in place, whatever layout the device keeps the cache
+    in (a scatter would have the TPU relayout the whole cache). An
+    inactive slot (pos -1) writes back the row it already holds."""
+    L, B, _, Hk, hd = cache["k"].shape
+    z = jnp.int32(0)
+    out = dict(cache)
+    for b in range(B):
+        live = pos[b] >= 0
+        at = (z, jnp.int32(b), jnp.where(live, pos[b], z), z, z)
+        for n in out:
+            old = jax.lax.dynamic_slice(out[n], at, (L, 1, 1, Hk, hd))
+            out[n] = jax.lax.dynamic_update_slice(
+                out[n], jnp.where(live, rows[n][:, b:b + 1], old), at)
+    return out
 
 
 _FLASH_THRESHOLD = 2048   # above this, use blocked attention

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from repro.models.config import ArchConfig
 from repro.models.layers import (attention, attention_init, embed,
                                  embedding_init, lm_head, mlp, mlp_init,
-                                 pos_vector, rmsnorm, rmsnorm_init)
+                                 pos_vector, rmsnorm, rmsnorm_init,
+                                 write_rows)
 from repro.models.moe import moe, moe_init
 from repro.models.sharding import shard
 
@@ -126,7 +127,10 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
     (B, 1, d) hidden states the LM head — dense `lm_head` or a
     compressed `SparseLinear` — consumes. `decode_step` is exactly
     ``lm_head(decode_hidden(...))``; the serving engine calls this
-    directly when the output projection is sparse."""
+    directly when the output projection is sparse. The layers only
+    read ``caches``; the step's new K/V rows are written into it once,
+    after the scan (`write_rows`), in place where the caller's jit
+    donates it."""
     x = embed(params["embed"], token)
     B = token.shape[0]
     pos = pos_vector(pos, B)          # (B,); -1 marks an inactive slot
@@ -134,13 +138,13 @@ def decode_hidden(params, cfg: ArchConfig, caches, token, pos):
 
     def body(x, inp):
         layer_p, cache = inp
-        x, _, new_cache = _layer_apply(cfg, layer_p, x, positions,
-                                       kv_cache=cache, cache_pos=pos)
-        return x, new_cache
+        x, _, rows = _layer_apply(cfg, layer_p, x, positions,
+                                  kv_cache=cache, cache_pos=pos)
+        return x, rows
 
-    x, new_caches = jax.lax.scan(body, x, (params["layers"], caches))
+    x, rows = jax.lax.scan(body, x, (params["layers"], caches))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return x, new_caches
+    return x, write_rows(caches, rows, pos)
 
 
 def decode_step(params, cfg: ArchConfig, caches, token, pos):

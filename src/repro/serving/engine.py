@@ -15,6 +15,14 @@ never serve correctly — empty prompts and
 ``prompt_len + max_new_tokens > max_seq`` — at `submit` time, which
 makes a slot position walking past ``max_seq`` unreachable.
 
+The pooled cache (`Engine.cache`, f32) is updated in place: the jitted
+decode step and slot insert take it as a donated argument, so XLA
+writes the step's one K/V row per slot (or the admitted slot's lines)
+into the same buffers, and no step copies the whole cache. The cache a
+call was given is consumed by it: nothing may hold on to
+`Engine.cache` across a step. ``engine.cache_in_place`` counts the
+calls whose donated cache was consumed.
+
 Sampling: ``greedy=True`` (default) takes the argmax;
 ``greedy=False`` samples from the temperature-scaled softmax,
 optionally truncated to the ``top_k`` most likely tokens, with a
@@ -107,6 +115,7 @@ class Engine:
         self._m_rejected = m.counter("engine.rejections")
         self._m_refills = m.counter("engine.refills_total")
         self._m_d2h = m.counter("engine.d2h_bytes")
+        self._m_in_place = m.counter("engine.cache_in_place")
         self._m_queue = m.gauge("engine.queue_depth")
         #: True when the last `run_until_drained` hit ``max_steps`` with
         #: requests still active (only reachable with on_truncate="warn").
@@ -132,7 +141,9 @@ class Engine:
         self._blank_slot = api.make_decode_cache(cfg, 1, max_seq,
                                                  dtype=jnp.float32)
         # Named functions, so that a device trace shows the modules as
-        # jit_engine_decode, jit_engine_decode_hidden, jit_engine_prefill.
+        # jit_engine_decode, jit_engine_decode_hidden, jit_engine_prefill,
+        # jit_engine_insert_slot. The cache (argument c) is donated to
+        # every jit that returns it: the result reuses its buffers.
         def engine_decode(p, c, t, pos):
             return api.decode_step(p, cfg, c, t, pos)
 
@@ -149,9 +160,20 @@ class Engine:
         def engine_prefill(p, b):
             return api.prefill(p, cfg, b, max_seq=max_seq)
 
-        self._decode = jax.jit(engine_decode)
-        self._decode_hidden = jax.jit(engine_decode_hidden)
+        # The slot is traced, so one program serves every slot.
+        def engine_insert_slot(c, req, slot):
+            return api.cache_insert_slot(cfg, c, req, slot)
+
+        self._decode = jax.jit(engine_decode, donate_argnums=1)
+        self._decode_hidden = jax.jit(engine_decode_hidden, donate_argnums=1)
         self._prefill = jax.jit(engine_prefill)
+        self._insert_slot = jax.jit(engine_insert_slot, donate_argnums=0)
+
+    def _count_in_place(self, given) -> None:
+        """Count a call whose donated cache ``given`` was consumed: its
+        buffers went to the result, so the call copied no cache."""
+        if all(a.is_deleted() for a in jax.tree.leaves(given)):
+            self._m_in_place.add(1)
 
     # --- sparse head ---------------------------------------------------------
     @classmethod
@@ -278,8 +300,9 @@ class Engine:
             # lines still hold its previous occupant's state.
             req_cache = self._blank_slot
         with obs.span("engine.insert_slot"):
-            self.cache = api.cache_insert_slot(self.cfg, self.cache,
-                                               req_cache, s)
+            given = self.cache
+            self.cache = self._insert_slot(given, req_cache, s)
+            self._count_in_place(given)
         self.pos[s] = L - 1
 
     # --- sampling --------------------------------------------------------------
@@ -329,6 +352,7 @@ class Engine:
             t_dec0 = time.perf_counter()
             with obs.span("engine.decode", batch=n_active,
                           sparse=self.sparse_head is not None):
+                given = self.cache
                 if self.sparse_head is not None:
                     # hidden-state decode, then the compressed LM head:
                     # the pooled (slots, 1, d) hidden states contract
@@ -337,13 +361,12 @@ class Engine:
                     # dense in-model head is never consulted in sparse
                     # mode.
                     hidden, self.cache = self._decode_hidden(
-                        self.params, self.cache, jnp.asarray(toks), pos)
+                        self.params, given, jnp.asarray(toks), pos)
                     logits = self._head(hidden)
                 else:
-                    logits, self.cache = self._decode(self.params,
-                                                      self.cache,
-                                                      jnp.asarray(toks),
-                                                      pos)
+                    logits, self.cache = self._decode(
+                        self.params, given, jnp.asarray(toks), pos)
+                self._count_in_place(given)
                 nbytes = logits.nbytes     # crosses in the logits' dtype
                 self._m_d2h.add(nbytes)
                 with obs.span("engine.logits_d2h", bytes=nbytes):

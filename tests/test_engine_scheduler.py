@@ -408,8 +408,46 @@ class TestSpans:
         step = (eng.params, eng.cache, jnp.zeros((2, 1), jnp.int32),
                 jnp.asarray(eng.pos))
         prompt = {"inputs": jnp.zeros((1, 3), jnp.int32)}
+        insert = (eng.cache, eng._blank_slot, 0)
         for fn, args, name in (
                 (eng._decode, step, "jit_engine_decode"),
                 (eng._decode_hidden, step, "jit_engine_decode_hidden"),
-                (eng._prefill, (eng.params, prompt), "jit_engine_prefill")):
+                (eng._prefill, (eng.params, prompt), "jit_engine_prefill"),
+                (eng._insert_slot, insert, "jit_engine_insert_slot")):
             assert f"module @{name} " in fn.lower(*args).as_text()
+
+    @pytest.mark.parametrize("arch", ["smollm-135m", "zamba2-7b"])
+    def test_jitted_steps_donate_the_cache(self, arch):
+        """Every jit that returns the pooled cache takes it donated, and
+        nothing else: its lowered module aliases each cache leaf to an
+        output buffer."""
+        cfg, params = _params_for(arch, vocab=64, seed=5)
+        eng = Engine(cfg, params, slots=2, max_seq=16,
+                     metrics=obs.MetricsRegistry())
+        n_cache = len(jax.tree.leaves(eng.cache))
+        step = (eng.params, eng.cache, jnp.zeros((2, 1), jnp.int32),
+                jnp.asarray(eng.pos))
+        for fn, args, at in (
+                (eng._decode, step, 1),
+                (eng._decode_hidden, step, 1),
+                (eng._insert_slot, (eng.cache, eng._blank_slot, 0), 0)):
+            lowered = fn.lower(*args)
+            donated = [jax.tree.leaves(jax.tree.map(lambda a: a.donated, x))
+                       for x in lowered.args_info[0]]
+            for i, flags in enumerate(donated):
+                assert flags == [i == at] * len(flags)
+            assert lowered.as_text().count("tf.aliasing_output") == n_cache
+
+    def test_cache_in_place_counts_every_step_and_admission(self):
+        """The CPU backend honours donation, so every decode step and
+        every admission consumes the cache it was given."""
+        cfg, params = _params_for("smollm-135m", vocab=64, seed=5)
+        m = obs.MetricsRegistry()
+        eng = Engine(cfg, params, slots=2, max_seq=32, metrics=m)
+        for p in _prompts(cfg, (1, 4, 6)):
+            eng.submit(p, 3)
+        eng.run_until_drained()
+        steps = m.counter("engine.steps_total").value
+        admissions = m.counter("engine.refills_total").value
+        assert (steps, admissions) == (6, 3)
+        assert m.counter("engine.cache_in_place").value == steps + admissions

@@ -8,7 +8,10 @@ at the shapes it serves — the smollm-135m LM head (49152 x 576, sparsity
 0.8, lane width 128), the yi9b-batch cell's head (8000 x 4096) and an MLP
 down projection (576 x 1536) — and check
 that the program holds a ``tpu_custom_call`` whose operands and results
-are all 32-bit.  Nothing runs; a compile that passes is not a chip run.
+are all 32-bit.  The engine's pooled decode step and slot insert are
+compiled at both benchmark cells' sizes, to check that the donated f32
+cache is updated in place.  Nothing runs; a compile that passes is not a
+chip run.
 
 The topology is described inside a fixture, never while a module is
 imported, so every test worker collects the same tests and only the one
@@ -180,3 +183,42 @@ def test_sell_grid_tiles_compile(spec):
                                               bn=8, tile_mode="grid"),
              spec(ell, jnp.int32), spec(ell, jnp.float32),
              spec((HEAD_N, 32), jnp.float32))
+
+
+#: The benchmark cells' pooled caches: configuration, layers, slots,
+#: max_seq, vocabulary (smollm135m-chat and yi9b-batch).
+CELLS = {"smollm": ("smollm-135m", 30, 32, 2048, 49152),
+         "yi": ("yi-9b", 8, 16, 1280, 8000)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_pooled_decode_updates_cache_in_place(spec, cell):
+    """With the cache donated, the decode step and the slot insert hand
+    its buffers to their results and hold no cache-sized temporary: no
+    relayout of the cache for a scatter, no bf16 copy of it hoisted out
+    of the layer scan, no rebuilt cache per step."""
+    from repro.configs import get
+    from repro.models import api
+    name, layers, slots, max_seq, vocab = CELLS[cell]
+    cfg = get(name).with_(n_layers=layers, vocab=vocab)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+    params = shapes(jax.eval_shape(
+        lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = shapes(jax.eval_shape(lambda: api.make_decode_cache(
+        cfg, slots, max_seq, dtype=jnp.float32)))
+    req = shapes(jax.eval_shape(lambda: api.make_decode_cache(
+        cfg, 1, max_seq, dtype=cfg.param_dtype)))
+    cache_bytes = sum(a.size * 4 for a in jax.tree.leaves(cache))
+    step = jax.jit(lambda p, c, t, q: api.decode_hidden(p, cfg, c, t, q),
+                   donate_argnums=1)
+    insert = jax.jit(lambda c, r, s: api.cache_insert_slot(cfg, c, r, s),
+                     donate_argnums=0)
+    for fn, args in (
+            (step, (params, cache, spec((slots, 1), jnp.int32),
+                    spec((slots,), jnp.int32))),
+            (insert, (cache, req, spec((), jnp.int32)))):
+        mem = fn.lower(*args).compile().memory_analysis()
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.temp_size_in_bytes < cache_bytes / 100
